@@ -1,7 +1,7 @@
-"""Kernel micro-benches: Pallas (interpret mode on CPU — correctness +
-blocking structure, NOT wall-clock) vs the pure-jnp reference path, plus the
-analytic VMEM footprint per BlockSpec choice (what the Stream planner's
-Step-3 analogue reasons about)."""
+"""Kernel micro-benches: Pallas (interpreted on the CPU, compiled on a TPU;
+correctness + blocking structure, NOT wall-clock) vs the pure-jnp
+reference path, plus the analytic VMEM footprint per BlockSpec choice
+(what the Stream planner's Step-3 analogue reasons about)."""
 from __future__ import annotations
 
 import time
@@ -21,7 +21,7 @@ def _vmem_bytes_flash(bq, bk, d, dtype_bytes=2):
 
 
 def run(report=print):
-    report("== Pallas kernel block sweeps (interpret mode; VMEM footprints) ==")
+    report("== Pallas kernel block sweeps (VMEM footprints) ==")
     B, H, S, D = 1, 2, 512, 128
     q = jax.random.normal(KEY, (B, H, S, D), jnp.float32)
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, H, S, D), jnp.float32)
@@ -30,8 +30,7 @@ def run(report=print):
     out_rows = []
     report(f"{'kernel':16s} {'blocks':>12s} {'VMEM(KB)':>9s} {'max err':>10s}")
     for bq, bk in ((128, 128), (256, 256), (128, 512)):
-        out = ops.flash_attention(q, k, v, block_q=bq, block_kv=bk,
-                                  interpret=True)
+        out = ops.flash_attention(q, k, v, block_q=bq, block_kv=bk)
         err = float(jnp.abs(out - want).max())
         vm = _vmem_bytes_flash(bq, bk, D) / 1024
         report(f"{'flash_attn':16s} {f'{bq}x{bk}':>12s} {vm:9.1f} {err:10.2e}")
@@ -40,8 +39,7 @@ def run(report=print):
     qd = q[:, :, 0, :]
     wantd = ref.decode_attention_ref(qd, k, v, 400)
     for bk in (128, 256, 512):
-        out = ops.decode_attention(qd, k, v, jnp.int32(400), block_kv=bk,
-                                   interpret=True)
+        out = ops.decode_attention(qd, k, v, jnp.int32(400), block_kv=bk)
         err = float(jnp.abs(out - wantd).max())
         report(f"{'decode_attn':16s} {f'1x{bk}':>12s} "
                f"{(2 * bk * D * 2 + D * 4) / 1024:9.1f} {err:10.2e}")
@@ -52,7 +50,7 @@ def run(report=print):
     wantm = ref.moe_gemm_ref(x, w)
     for bm, bn, bkk in ((64, 64, 64), (128, 128, 128)):
         out = ops.grouped_expert_gemm(x, w, block_m=bm, block_n=bn,
-                                      block_k=bkk, interpret=True)
+                                      block_k=bkk)
         err = float(jnp.abs(out - wantm).max() / jnp.abs(wantm).max())
         report(f"{'moe_gemm':16s} {f'{bm}x{bn}x{bkk}':>12s} "
                f"{(bm * bkk + bkk * bn) * 2 / 1024 + bm * bn * 4 / 1024:9.1f} "

@@ -95,6 +95,8 @@ def main() -> None:
             print(f"{slug:{width}s}  {name}")
         return
 
+    from repro.backend import enable_compilation_cache
+    enable_compilation_cache()
     t00 = time.perf_counter()
     failures = []
     only = [t.strip() for t in args.only.split(",") if t.strip()]

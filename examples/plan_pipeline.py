@@ -10,6 +10,7 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, SHAPES, reduce_config
@@ -17,7 +18,6 @@ from repro.core.planner import evaluate_pipeline
 from repro.models.module import init_from_specs
 from repro.models.zoo import build_param_specs
 from repro.train.pipeline import make_pipeline_loss
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
 
 cfg_full = ARCHS["deepseek-67b"]
 shape = SHAPES["train_4k"]
@@ -33,7 +33,8 @@ for prio in ("latency", "memory"):
 
 print("\nexecuting a 2-stage pipeline on host devices (reduced config):")
 cfg = reduce_config(ARCHS["llama3.2-3b"], n_layers=4)
-mesh = compat_make_mesh((2, 2), ("pipe", "data"))
+mesh = jax.make_mesh((2, 2), ("pipe", "data"),
+                     axis_types=(AxisType.Auto,) * 2)
 params = init_from_specs(build_param_specs(cfg), jax.random.PRNGKey(0))
 params["layers"] = jax.tree.map(lambda a: a.reshape((2, 2) + a.shape[1:]),
                                 params["layers"])
@@ -41,7 +42,7 @@ key = jax.random.PRNGKey(1)
 batch = {"tokens": jax.random.randint(key, (4, 32), 0, cfg.vocab),
          "labels": jax.random.randint(key, (4, 32), 0, cfg.vocab)}
 loss_fn = make_pipeline_loss(cfg, mesh, n_stages=2, n_microbatches=2)
-with compat_set_mesh(mesh):
+with jax.set_mesh(mesh):
     loss, grads = jax.value_and_grad(loss_fn)(params, batch)
 print(f"pipeline loss={float(loss):.4f}; grads flow through ppermute: "
       f"{all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))}")

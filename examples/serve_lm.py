@@ -6,5 +6,5 @@ concurrent requests (reduced llama config).
 from repro.launch.serve import main
 
 if __name__ == "__main__":
-    main(["--arch", "llama3.2-3b", "--requests", "4", "--max-new", "16",
-          "--prompt-len", "32"])
+    main(["--arch", "llama3.2-3b", "--smoke", "--requests", "4",
+          "--max-new", "16", "--prompt-len", "32"])

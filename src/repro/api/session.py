@@ -1434,17 +1434,24 @@ class ExplorationSession:
 
     def _make_executor(self, executor: "str | SweepExecutor",
                        max_workers: int | None) -> SweepExecutor:
-        if isinstance(executor, SweepExecutor):
-            return executor
         if executor == "serial":
             return SerialExecutor(self)
         if executor == "process":
-            return ProcessExecutor(max_workers or self.max_workers,
-                                   retry_policy=self.retry_policy,
-                                   fault_injector=self.fault_injector,
-                                   deadline_s=self.deadline_s)
-        raise ValueError(f"unknown executor {executor!r} "
-                         "(expected 'serial' or 'process')")
+            executor = ProcessExecutor(max_workers or self.max_workers,
+                                       retry_policy=self.retry_policy,
+                                       fault_injector=self.fault_injector,
+                                       deadline_s=self.deadline_s)
+        if not isinstance(executor, SweepExecutor):
+            raise ValueError(f"unknown executor {executor!r} "
+                             "(expected 'serial' or 'process')")
+        # the batched fitness runs on the device, which belongs to the
+        # process holding it: a worker would either score without it or
+        # fight this process for the chip
+        if self.prefilter and isinstance(executor, ProcessExecutor):
+            raise ValueError(
+                "prefilter=True scores on the device held by this process; "
+                "process workers cannot share it — use executor='serial'")
+        return executor
 
     def _start_sweep(self, space, executor, max_workers, warm_start, order,
                      policies, progress,

@@ -16,7 +16,7 @@ evaluates a whole `(P, G)` population at once:
   cumsum/cummax prefix ops (`repro.kernels.ref.serialize_prefix_ref`), and
   the `(P x n_cores)` per-wavefront resource update runs as a Pallas kernel
   (`repro.kernels.wavefront.serialize_prefix`) when `use_pallas` is on —
-  `interpret=True` on CPU-only jax via `jax_compat`.
+  interpreted only on the CPU (`repro.backend`).
 
 The result is a *fitness approximation*: global heap order collapses to
 wavefront order, fresh-byte dedup and spill feedback are dropped, weights
@@ -83,9 +83,9 @@ class BatchedFitness:
     `prefilter` packages the scalarized approximate score for
     `GeneticAllocator(prefilter=...)`.
 
-    `use_pallas=None` enables the Pallas serialization kernel only on
-    device backends; `True` forces it (interpreted on CPU), `False` keeps
-    the pure-jnp reference path.
+    `use_pallas=None` enables the Pallas serialization kernel unless the
+    work runs on the CPU (`repro.backend.platform`); `True` forces it
+    (interpreted on CPU), `False` keeps the pure-jnp reference path.
     """
 
     def __init__(self, engine, priority: str = "latency",
@@ -101,7 +101,9 @@ class BatchedFitness:
         self.strict_layers = strict_layers
         self.max_batch = int(max_batch)
         import jax
-        device = jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
+
+        from repro.backend import platform
+        device = platform() != "cpu"
         if use_pallas is None:
             use_pallas = device
         self.use_pallas = bool(use_pallas)
@@ -504,11 +506,15 @@ class BatchedFitness:
             if comm:
                 # ...and are freed from the *producer's* core when the
                 # consumer finishes: per-core mask-sums over the pred view
-                # plus one static matmul onto the consumer's level
+                # plus one static matmul onto the consumer's level (at
+                # full f32 precision: the TPU's default rounds operands to
+                # bf16, which would blur the byte counts)
                 fbe = f8n / 8.0                        # (n+1, D, P)
                 lvl_t = j["lvl_oh"].T                  # (L, n+1)
-                cols = [lvl_t @ jnp.sum(jnp.where(pucn == c, fbe, 0.0),
-                                        axis=1) for c in range(n_cores)]
+                cols = [jnp.matmul(
+                    lvl_t, jnp.sum(jnp.where(pucn == c, fbe, 0.0), axis=1),
+                    precision=jax.lax.Precision.HIGHEST)
+                    for c in range(n_cores)]
                 fc = fc + jnp.stack(cols, axis=1)      # (L, C, P)
             xs["fc"] = fc
 

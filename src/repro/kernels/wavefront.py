@@ -11,24 +11,41 @@ row-block shape Pallas wants: each grid step loads a `(rows, W)` tile of
 release/duration rows plus its `(rows, 1)` availability column into VMEM
 and writes the serialized finish times back.
 
-On CPU-only jax the kernel runs in `interpret=True` mode (the
-`jax_compat.compat_pallas_interpret` default), which executes the same lax
-program under jit; on TPU/GPU it compiles natively.
+Mosaic has no lowering for `cumsum`/`cummax`, so both prefix ops are
+in-register Hillis-Steele scans: ceil(log2 W) steps of a lane rotation
+(`pltpu.roll`) masked by an iota. The adds happen in exactly the order of
+`repro.kernels.ref.prefix_sum`, so kernel and reference agree bit for bit.
+The kernel is interpreted only on the CPU (`repro.backend.pallas_interpret`)
+and compiles natively everywhere else.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.jax_compat import compat_pallas_interpret
+from repro.backend import pallas_interpret
+
+NEG = -1e30     # prefix-max identity ("not queued"), as in the reference
+
+
+def _scan(x, op, identity):
+    """Inclusive prefix `op` over the lane axis of a (rows, W) tile."""
+    w = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    k = 1
+    while k < w:
+        x = op(x, jnp.where(lane >= k, pltpu.roll(x, k, 1), identity))
+        k *= 2
+    return x
 
 
 def _serialize_kernel(free_ref, rel_ref, dur_ref, fin_ref, free_out_ref):
     d = dur_ref[...]
-    s = jnp.cumsum(d, axis=-1)
+    s = _scan(d, jnp.add, 0.0)
     g = rel_ref[...] - (s - d)
-    run = jnp.maximum(jax.lax.cummax(g, axis=1), free_ref[...])
+    run = jnp.maximum(_scan(g, jnp.maximum, NEG), free_ref[...])
     fin = s + run
     fin_ref[...] = fin
     free_out_ref[...] = fin[:, -1:]
@@ -43,7 +60,7 @@ def serialize_prefix(free0, release, dur, *, block_rows: int = 128,
     rows and processed in `block_rows` tiles.
     """
     if interpret is None:
-        interpret = compat_pallas_interpret()
+        interpret = pallas_interpret()
     w = release.shape[-1]
     lead = release.shape[:-1]
     rel = release.reshape(-1, w)

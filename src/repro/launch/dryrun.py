@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.analysis.roofline import analyze_compiled
 from repro.configs import ARCHS, SHAPES
-from repro.launch.mesh import compat_set_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import zoo
 from repro.models.module import abstract_from_specs
 from repro.sharding.rules import sharding_for, tree_shardings
@@ -63,7 +63,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     data_sh = batch_shardings(data_specs, mesh)
     t0 = time.perf_counter()
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             sspecs = train_state_specs(pspecs, step_cfg)
             state_abs = abstract_from_specs(sspecs)

@@ -10,19 +10,18 @@ Defined as FUNCTIONS so importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-from repro.jax_compat import compat_make_mesh, compat_set_mesh  # noqa: F401
-# (re-exported: tests and launch scripts import the compat shims from here)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """A mesh over whatever devices exist (CPU smoke tests / examples)."""
     n = len(jax.devices())
     mp = model_parallel if n % model_parallel == 0 else 1
-    return compat_make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -1,6 +1,9 @@
-"""CLI serve driver (batched requests on the reduced config).
+"""CLI serve driver: batched requests at the published widths, or on the
+reduced same-family config under `--smoke`.
 
-Engine mode runs the real jit'd token loop:
+Engine mode runs the real jit'd token loop, with parameters and caches
+placed by their sharding rules on the host mesh (`--model-parallel` sizes
+its 'model' axis):
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --smoke \
       --requests 4 --max-new 16
@@ -21,22 +24,30 @@ import argparse
 import time
 
 
+def engine_config(args):
+    """The model config engine mode serves: the published widths, or the
+    reduced same-family config under `--smoke`."""
+    from repro.configs import ARCHS, reduce_config
+    cfg = ARCHS[args.arch]
+    return reduce_config(cfg) if args.smoke else cfg
+
+
 def _run_engine(args):
     import jax
     import numpy as np
 
-    from repro.configs import ARCHS, reduce_config
+    from repro.backend import enable_compilation_cache
     from repro.launch.mesh import make_host_mesh, make_production_mesh
-    from repro.models.module import init_from_specs
+    from repro.models.module import init_sharded
     from repro.models.zoo import build_param_specs
     from repro.serve.engine import Request, ServeEngine
 
-    cfg = ARCHS[args.arch]
-    if args.smoke:
-        cfg = reduce_config(cfg)
+    enable_compilation_cache()
+    cfg = engine_config(args)
     mesh = (make_production_mesh() if args.production_mesh
-            else make_host_mesh())
-    params = init_from_specs(build_param_specs(cfg), jax.random.PRNGKey(0))
+            else make_host_mesh(args.model_parallel))
+    params = init_sharded(build_param_specs(cfg), jax.random.PRNGKey(0),
+                          mesh)
     engine = ServeEngine(cfg, params, mesh=mesh, batch_slots=args.batch_slots,
                          max_len=args.prompt_len + args.max_new + 8,
                          prompt_len=args.prompt_len)
@@ -53,7 +64,7 @@ def _run_engine(args):
           f"peak occupancy {engine.max_active}/{engine.B}")
     for i, r in enumerate(reqs):
         print(f"req{i}: {r.out_tokens[:12]}...")
-    return reqs
+    return engine, reqs
 
 
 def _run_simulator(args):
@@ -81,16 +92,20 @@ def _run_simulator(args):
     return sweep
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced same-family config instead of "
+                         "the published widths")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--batch-slots", type=int, default=None,
                     help="slot-pool size (default: --requests)")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--production-mesh", action="store_true")
+    ap.add_argument("--model-parallel", type=int, default=1,
+                    help="'model' axis size of the host mesh")
     ap.add_argument("--simulate", action="store_true",
                     help="analytic closed-loop simulator instead of the "
                          "token engine")
@@ -108,6 +123,13 @@ def main(argv=None):
         args.batch_slots = args.requests
     if args.rate is None:
         args.rate = [1000.0]
+    return args
+
+
+def main(argv=None):
+    """Engine mode returns `(engine, requests)`; `--simulate` returns the
+    serving sweep."""
+    args = parse_args(argv)
     if args.simulate:
         return _run_simulator(args)
     return _run_engine(args)

@@ -16,18 +16,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend import enable_compilation_cache
 from repro.configs import ARCHS, reduce_config
-from repro.launch.mesh import (compat_set_mesh, make_host_mesh,
-                               make_production_mesh)
-from repro.models.module import init_from_specs
+from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.models.module import init_sharded
 from repro.models.zoo import build_param_specs
-from repro.sharding.rules import tree_shardings
 from repro.train import checkpoint as ckpt
 from repro.train.data import DataConfig, TokenStream
 from repro.train.fault_tolerance import resume_or_init
 from repro.train.optimizer import AdamWConfig
-from repro.train.train_step import (TrainStepConfig, init_train_state,
-                                    make_train_step)
+from repro.train.train_step import (TrainStepConfig, make_train_step,
+                                    train_state_specs)
 
 
 def main(argv=None):
@@ -47,6 +46,7 @@ def main(argv=None):
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--layers", type=int, default=4)
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     cfg = ARCHS[args.arch]
     if args.smoke:
@@ -63,15 +63,15 @@ def main(argv=None):
         opt=AdamWConfig(lr=args.lr, total_steps=args.steps,
                         warmup_steps=min(20, args.steps // 5)))
     pspecs = build_param_specs(cfg)
-    params_sh = tree_shardings(pspecs, mesh)
+    opt_specs = train_state_specs(pspecs, step_cfg)
 
     data = TokenStream(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                                   global_batch=args.batch, seed=args.seed))
 
     def init_all():
-        params = init_from_specs(pspecs, jax.random.PRNGKey(args.seed))
-        return {"params": params,
-                "opt": init_train_state(cfg, params, step_cfg)}
+        key = jax.random.PRNGKey(args.seed)
+        return {"params": init_sharded(pspecs, key, mesh),
+                "opt": init_sharded(opt_specs, key, mesh)}
 
     start = 0
     if args.ckpt_dir:
@@ -80,14 +80,16 @@ def main(argv=None):
         if start:
             print(f"resumed from step {start}")
             tmpl = init_all()
-            state = ckpt.restore(args.ckpt_dir, start, like_tree=tmpl)
+            state = ckpt.restore(
+                args.ckpt_dir, start, like_tree=tmpl,
+                shardings=jax.tree.map(lambda a: a.sharding, tmpl))
     else:
         state = init_all()
 
     train_step = jax.jit(make_train_step(cfg, mesh, step_cfg),
                          donate_argnums=(0, 1))
     params, opt = state["params"], state["opt"]
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t_last = time.perf_counter()
         for step in range(start, args.steps):
             batch = {k: jnp.asarray(v) for k, v in

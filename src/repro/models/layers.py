@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.module import ParamSpec
-from repro.jax_compat import compat_shard_map
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -222,7 +221,7 @@ def decode_attention_kv_sharded(q, k_cache, v_cache, cur_len, mesh,
         out = acc_all / jnp.maximum(l_all, 1e-30)[..., None]
         return out.reshape(B, 1, Hq, D).astype(q.dtype)
 
-    return compat_shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(None, ax, None, None), P(None, ax, None, None), P()),
         out_specs=P(), check_vma=False,
@@ -375,7 +374,7 @@ def moe_ffn(params, x, *, top_k: int, mesh, dp_axes=("pod", "data"),
                        params["shared"]["down"])
         shared_specs = (P(None, tp), P(None, tp), P(tp, None))
 
-    out, aux = compat_shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(),
                   P(None, None, tp), P(None, None, tp),

@@ -3,7 +3,8 @@
 Params are nested dicts of jnp arrays. Every model declares a *spec tree* of
 `ParamSpec(shape, dtype, axes, init)` where `axes` are logical sharding axes
 ('data' / 'model' / 'expert' / None per dim); `init_from_specs` materializes
-real arrays (smoke tests / training), `abstract_from_specs` materializes
+real arrays (smoke tests / training), `init_sharded` the same arrays placed
+by those axes on a mesh (launchers, serving), `abstract_from_specs`
 ShapeDtypeStructs with NamedShardings (dry-run: no allocation).
 """
 from __future__ import annotations
@@ -54,6 +55,17 @@ def init_from_specs(specs, key: jax.Array, dtype_override=None):
             scale = spec.scale if spec.scale is not None else 1.0 / math.sqrt(max(fan_in, 1))
             out.append((jax.random.normal(k, spec.shape, jnp.float32) * scale).astype(dtype))
     return jax.tree.unflatten(treedef, out)
+
+
+def init_sharded(specs, key: jax.Array, mesh):
+    """`init_from_specs` as one program whose outputs carry each leaf's
+    `NamedSharding` on `mesh`: every device draws only its own shards (no
+    stacking on device 0), and the float32 draw fuses into the cast instead
+    of a whole float32 leaf sitting on the device. The values are those of
+    `init_from_specs` up to the rounding of a last bit in the fused cast."""
+    from repro.sharding.rules import tree_shardings
+    return jax.jit(lambda k: init_from_specs(specs, k),
+                   out_shardings=tree_shardings(specs, mesh))(key)
 
 
 def abstract_from_specs(specs):

@@ -24,8 +24,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import zoo
-from repro.models.module import init_from_specs
-from repro.launch.mesh import compat_set_mesh
+from repro.models.module import init_sharded
 from repro.serve.batching import SlotBatcher
 
 
@@ -46,8 +45,8 @@ class ServeEngine:
         self.B = batch_slots
         self.max_len = max_len
         self.prompt_len = prompt_len
-        cspecs = zoo.build_cache_specs(cfg, batch_slots, max_len)
-        self.caches = init_from_specs(cspecs, jax.random.PRNGKey(0))
+        self._cache_specs = zoo.build_cache_specs(cfg, batch_slots, max_len)
+        self.caches = self._new_caches()
         self.cur_len = 0
         self.slots: list[Request | None] = [None] * batch_slots
 
@@ -61,21 +60,43 @@ class ServeEngine:
         self._prefill = jax.jit(_prefill, donate_argnums=(2,))
         self._decode = jax.jit(_decode, donate_argnums=(2,))
 
-    # ---- step methods (one jit'd program each) -----------------------
-    def prefill_step(self, requests: list[Request]):
-        """Batched prefill for up to `batch_slots` requests: fills each
-        slot's cache region, resets the sequence clock to `prompt_len`,
-        and returns the first greedily sampled token per slot."""
+    def _new_caches(self):
+        """Zeroed caches placed by their sharding rules on the mesh."""
+        return init_sharded(self._cache_specs, jax.random.PRNGKey(0),
+                            self.mesh)
+
+    def prompt_batch(self, requests: list[Request]) -> np.ndarray:
+        """(batch_slots, prompt_len) token ids: each request's prompt
+        left-padded (or truncated to its tail) in its slot."""
         assert len(requests) <= self.B
         S = self.prompt_len
         prompts = np.zeros((self.B, S), np.int32)
         for i, r in enumerate(requests):
             p = r.prompt[-S:]
             prompts[i, S - len(p):] = p
+        return prompts
+
+    # ---- step methods (one jit'd program each) -----------------------
+    def prefill_step(self, requests: list[Request]):
+        """Batched prefill for up to `batch_slots` requests: fills each
+        slot's cache region, resets the sequence clock to `prompt_len`,
+        and returns the first greedily sampled token per slot."""
         logits, self.caches = self._prefill(
-            self.params, {"tokens": jnp.asarray(prompts)}, self.caches)
-        self.cur_len = S
+            self.params, {"tokens": jnp.asarray(self.prompt_batch(requests))},
+            self.caches)
+        self.cur_len = self.prompt_len
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def prefill_logits(self, requests: list[Request]):
+        """Last-token logits of the same compiled prefill on fresh caches,
+        leaving the engine's state untouched: the served program's output
+        for comparison against a reference."""
+        with jax.set_mesh(self.mesh):
+            logits, _ = self._prefill(
+                self.params,
+                {"tokens": jnp.asarray(self.prompt_batch(requests))},
+                self._new_caches())
+        return logits
 
     def decode_once(self, tok):
         """One decode step for every slot: consumes the previous token
@@ -90,7 +111,7 @@ class ServeEngine:
     def run(self, requests: list[Request], greedy: bool = True):
         """Serve a batch of requests to completion (batched prefill+decode)."""
         assert len(requests) <= self.B
-        with compat_set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             tok = self.prefill_step(requests)
             max_new = max(r.max_new_tokens for r in requests)
             for step in range(max_new):
@@ -113,7 +134,7 @@ class ServeEngine:
         """
         batcher = SlotBatcher(self.B)
         queue = list(range(len(requests)))
-        with compat_set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             while queue:
                 n_admit = min(batcher.free_slots(), len(queue))
                 cohort = [queue.pop(0) for _ in range(n_admit)]

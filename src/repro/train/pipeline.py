@@ -20,7 +20,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models import transformer as tfm
 from repro.models.module import is_spec, spec_tree_map
-from repro.jax_compat import compat_shard_map
 
 F32 = jnp.float32
 
@@ -117,7 +116,7 @@ def make_pipeline_loss(cfg: ArchConfig, mesh, *, n_stages: int,
             return jnp.where(stage == n_stages - 1, loss_acc, 0.0)
 
         head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        per_stage = compat_shard_map(
+        per_stage = jax.shard_map(
             stage_fn, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(axis), params["layers"]),
                       P(), P(), P(), P(), P()),

@@ -1,5 +1,6 @@
 """HLO walker + roofline + sharding rules + cost model unit tests."""
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,7 +8,6 @@ import pytest
 from repro.analysis.hlo import analyze
 from repro.analysis.roofline import Roofline
 from repro.sharding.rules import spec_for
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
 
 
 def test_walker_counts_scanned_dot_flops():
@@ -33,7 +33,8 @@ def test_walker_counts_collective_bytes():
     devs = jax.devices()
     if len(devs) < 2:
         pytest.skip("needs >1 device")
-    mesh = compat_make_mesh((len(devs),), ("d",))
+    mesh = jax.make_mesh((len(devs),), ("d",),
+                         axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):
@@ -41,7 +42,7 @@ def test_walker_counts_collective_bytes():
             x, NamedSharding(mesh, P(None))).sum() + x.sum()
 
     x_sh = NamedSharding(mesh, P("d"))
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(f, in_shardings=(x_sh,)).lower(
             jax.ShapeDtypeStruct((len(devs) * 8, 4), jnp.float32)).compile()
     a = analyze(compiled.as_text())
@@ -62,7 +63,8 @@ def test_roofline_terms_and_bottleneck():
 
 
 def test_sharding_rules_divisibility_fallback():
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     # divisible: sharded
     assert spec_for(("vocab", None), (512, 16), mesh)[0] == "model"
     # not divisible: replicated

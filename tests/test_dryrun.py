@@ -2,14 +2,15 @@
 small host mesh — exercises exactly what launch/dryrun.py does per cell,
 without the 512-device production setting."""
 import jax
+from jax.sharding import AxisType
 import pytest
 
 from repro.launch.dryrun import lower_cell
-from repro.launch.mesh import compat_make_mesh
 
 
 def _mesh(shape=(2, 4)):
-    return compat_make_mesh(shape, ("data", "model"))
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_lower_cell_train_reports_roofline():

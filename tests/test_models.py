@@ -2,13 +2,13 @@
 CPU, asserting shapes + finiteness), chunked-vs-scan equivalences, MoE
 semantics, decode-vs-full-forward consistency."""
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCHS, reduce_config
-from repro.models.module import init_from_specs
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
+from repro.models.module import init_from_specs, init_sharded
 from repro.models.zoo import (build_cache_specs, build_param_specs,
                               decode_step, prefill, train_loss)
 
@@ -18,7 +18,8 @@ MESH = None
 def mesh():
     global MESH
     if MESH is None:
-        MESH = compat_make_mesh((2, 4), ("data", "model"))
+        MESH = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     return MESH
 
 
@@ -40,7 +41,7 @@ def test_arch_smoke_train_step(arch):
     cfg = reduce_config(ARCHS[arch])
     params = init_from_specs(build_param_specs(cfg), jax.random.PRNGKey(0))
     batch = _batch(cfg)
-    with compat_set_mesh(mesh()):
+    with jax.set_mesh(mesh()):
         loss = train_loss(cfg, params, batch, mesh=mesh(), remat=False)
     assert jnp.isfinite(loss) and 3.0 < float(loss) < 12.0
 
@@ -54,7 +55,7 @@ def test_arch_smoke_prefill_decode(arch):
     batch.pop("labels")
     caches = init_from_specs(build_cache_specs(cfg, B, S + 4),
                              jax.random.PRNGKey(1))
-    with compat_set_mesh(mesh()):
+    with jax.set_mesh(mesh()):
         logits, caches = prefill(cfg, params, batch, caches, mesh=mesh())
         enc_out = None
         if cfg.family == "encdec":
@@ -86,7 +87,7 @@ def test_prefill_then_decode_matches_full_forward(arch):
     key = jax.random.PRNGKey(3)
     toks = jax.random.randint(key, (B, S + 1), 0, cfg.vocab)
     m = mesh()
-    with compat_set_mesh(m):
+    with jax.set_mesh(m):
         # full forward over S+1 tokens -> logits at position S-1 and S
         from repro.models import transformer as tfm
         x, _, _ = tfm.decoder_forward(cfg, params, toks, mesh=m)
@@ -150,7 +151,7 @@ def test_moe_capacity_matches_dense_when_unconstrained():
     specs = moe_specs(16, 8, n_routed=8, n_shared=1, dtype=jnp.float32)
     params = init_from_specs(specs, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(2), (4, 8, 16))
-    with compat_set_mesh(m):
+    with jax.set_mesh(m):
         out_cap, _ = moe_ffn(params, x, top_k=2, mesh=m, dp_axes=("data",),
                              impl="capacity", capacity_factor=8.0)
         out_rag, _ = moe_ffn(params, x, top_k=2, mesh=m, dp_axes=("data",),
@@ -173,3 +174,23 @@ def test_mrope_sections_rotate_independently():
     diff = apply_mrope(x, jnp.stack([pos, pos * 2, pos]), sections=(8, 4, 4),
                        theta=1e4)
     assert not np.allclose(np.asarray(diff), np.asarray(plain))
+
+
+def test_init_sharded_places_leaves_by_their_axes():
+    """Launchers materialize parameters sharded by their logical axes:
+    the values of `init_from_specs` (the fused float32 draw may round a
+    last bfloat16 bit the other way: 2**-7 relative), split across the
+    mesh."""
+    from repro.sharding.rules import tree_shardings
+    cfg = reduce_config(ARCHS["deepseek-moe-16b"])
+    specs = build_param_specs(cfg)
+    key = jax.random.PRNGKey(0)
+    want = init_from_specs(specs, key)
+    got = init_sharded(specs, key, mesh())
+    for w, g, sh in zip(jax.tree.leaves(want), jax.tree.leaves(got),
+                        jax.tree.leaves(tree_shardings(specs, mesh()))):
+        assert g.sharding.is_equivalent_to(sh, g.ndim)
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32), rtol=2**-7)
+    assert any(len({s.index for s in g.addressable_shards}) > 1
+               for g in jax.tree.leaves(got))

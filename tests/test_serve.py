@@ -7,21 +7,23 @@ shapes.  `serve` (continuous batching through `SlotBatcher`) must match
 `run` on each admission wave and drain arbitrarily many requests.
 """
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import ARCHS, reduce_config
 from repro.models import zoo
 from repro.models.module import init_from_specs
 from repro.models.zoo import build_param_specs
 from repro.serve.engine import Request, ServeEngine
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
 
 
 def _setup(batch_slots, prompt_len, max_len, mesh_shape=(1, 1)):
     cfg = reduce_config(ARCHS["llama3.2-3b"])
     params = init_from_specs(build_param_specs(cfg), jax.random.PRNGKey(0))
-    mesh = compat_make_mesh(mesh_shape, ("data", "model"))
+    mesh = jax.make_mesh(mesh_shape, ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     engine = ServeEngine(cfg, params, mesh=mesh, batch_slots=batch_slots,
                          max_len=max_len, prompt_len=prompt_len)
     return cfg, params, mesh, engine
@@ -33,7 +35,7 @@ def _reference_tokens(cfg, params, mesh, prompts, max_new, max_len):
     caches = init_from_specs(zoo.build_cache_specs(cfg, B, max_len),
                              jax.random.PRNGKey(0))
     outs = [[] for _ in range(B)]
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, caches = zoo.prefill(cfg, params,
                                      {"tokens": jnp.asarray(prompts)},
                                      caches, mesh=mesh)
@@ -124,3 +126,30 @@ def test_serve_on_multi_device_mesh():
     engine.serve(reqs)
     assert all(r.done and len(r.out_tokens) == 4 for r in reqs)
     assert all(0 <= t < cfg.vocab for r in reqs for t in r.out_tokens)
+
+
+def test_prefill_logits_reproduce_served_first_tokens():
+    """`prefill_logits` reruns the engine's own compiled prefill on fresh
+    caches: its argmax is the first token each request of the wave got."""
+    cfg, params, mesh, engine = _setup(batch_slots=2, prompt_len=16,
+                                       max_len=48)
+    rng = np.random.default_rng(4)
+    reqs = [Request(prompt=rng.integers(1, cfg.vocab, size=16),
+                    max_new_tokens=3) for _ in range(3)]
+    engine.serve(reqs)
+    logits = engine.prefill_logits(reqs[:2])
+    assert logits.shape == (2, cfg.vocab)
+    assert np.asarray(jnp.argmax(logits, axis=-1)).tolist() == \
+        [r.out_tokens[0] for r in reqs[:2]]
+
+
+@pytest.mark.parametrize("flags, reduced", [([], False), (["--smoke"], True)],
+                         ids=["published", "smoke"])
+def test_cli_serves_published_widths_unless_smoke(flags, reduced):
+    """Parsing only: without `--smoke` the CLI serves the config at its
+    published widths."""
+    from repro.launch import serve
+    cfg = serve.engine_config(serve.parse_args(["--arch", "rwkv6-3b",
+                                                *flags]))
+    full = ARCHS["rwkv6-3b"]
+    assert cfg == (reduce_config(full) if reduced else full)

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from repro.api import (DesignSpace, ExplorationSession, FifoCache, GAConfig,
-                       ResultStore)
+                       ProcessExecutor, ResultStore)
 from repro.configs.paper_workloads import fsrcnn, resnet18
 from repro.hw.catalog import mc_hetero, mc_hom_tpu, sc_tpu
 
@@ -137,6 +137,17 @@ def test_process_executor_bit_identical_to_serial():
 def test_unknown_executor_rejected():
     with pytest.raises(ValueError):
         ExplorationSession().run(_small_space(), executor="quantum")
+
+
+@pytest.mark.parametrize("executor", ["process", ProcessExecutor(2)],
+                         ids=["name", "instance"])
+def test_process_executor_refuses_prefilter(executor):
+    """The batched fitness must run in the process that holds the device:
+    a prefiltered sweep refuses process workers instead of silently
+    scoring without it."""
+    with pytest.raises(ValueError, match="prefilter"):
+        ExplorationSession(prefilter=True).run(_small_space(),
+                                               executor=executor)
 
 
 # ---------------------------------------------------------------------------

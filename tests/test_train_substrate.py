@@ -3,6 +3,7 @@ checkpointing, data determinism, fault tolerance, pipeline parallelism."""
 import os
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,7 +14,6 @@ from repro.models.zoo import build_param_specs
 from repro.train import checkpoint as ckpt
 from repro.train.data import DataConfig, TokenStream
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
 from repro.train.train_step import (TrainStepConfig, compress_grads,
                                     init_train_state, make_train_step)
 
@@ -24,7 +24,8 @@ _needs_zstandard = pytest.mark.skipif(
 
 
 def _mesh(shape=(2, 4), names=("data", "model")):
-    return compat_make_mesh(shape, names)
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def _tiny():
@@ -49,7 +50,7 @@ def test_train_loss_decreases():
     state = init_train_state(cfg, params, scfg)
     data = TokenStream(DataConfig(vocab=cfg.vocab, seq_len=32, global_batch=8))
     losses = []
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(25):
             batch = {k: jnp.asarray(v) for k, v in data.global_batch(i).items()}
             params, state, m = step(params, state, batch)
@@ -67,7 +68,7 @@ def test_microbatch_equivalence():
         scfg = TrainStepConfig(microbatches=mb, remat=False,
                                opt=AdamWConfig(lr=1e-3))
         step = make_train_step(cfg, mesh, scfg)
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p2, _, m = step(jax.tree.map(jnp.copy, params),
                             init_train_state(cfg, params, scfg), batch)
         outs[mb] = (p2, float(m["loss"]))
@@ -196,10 +197,11 @@ def test_pipeline_loss_matches_reference():
     from repro.models.zoo import train_loss
     from repro.train.pipeline import make_pipeline_loss
     cfg = reduce_config(ARCHS["llama3.2-3b"], n_layers=4)
-    mesh = compat_make_mesh((2, 2), ("pipe", "data"))
+    mesh = jax.make_mesh((2, 2), ("pipe", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     params = init_from_specs(build_param_specs(cfg), jax.random.PRNGKey(0))
     batch = _tiny_batch(cfg, B=4, S=32)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         ref = train_loss(cfg, params, batch, mesh=mesh, remat=False)
         p2 = dict(params)
         p2["layers"] = jax.tree.map(
