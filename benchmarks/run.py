@@ -61,8 +61,6 @@ BENCHES = [
      lambda a: {"full": a.full, "workers": a.workers}),
     ("serving", "closed-loop serving (SLO-vs-QPS curves)",
      "benchmarks.bench_serving", lambda a: {"full": a.full}),
-    ("obs", "observability: tracer overhead (sim-time channel)",
-     "benchmarks.bench_obs", lambda a: {"full": a.full}),
     ("kernels", "kernels (Pallas blocks)",
      "benchmarks.bench_kernels", lambda a: {}),
     ("pipeline_plan", "pipeline planner (beyond-paper)",

@@ -48,6 +48,7 @@ from repro.core.stream_api import StreamResult, core_symmetry_cache_key, \
     core_symmetry_canonicalize, hw_min_tiles
 from repro.core.workload import Workload
 from repro.hw.accelerator import Accelerator
+from repro.obs.tracing import NULL_TRACER
 
 DEFAULT_GRANULARITIES = ("layer", ("tile", 8, 1), ("tile", 16, 1),
                          ("tile", 32, 1), ("tile", 64, 1))
@@ -1120,80 +1121,86 @@ class ExplorationSession:
         generation's novel offspring through the batched approximate
         evaluator (`repro.core.vectorized.BatchedFitness`) and prunes the
         worst-ranked before exact rescoring; reported metrics always come
-        from the exact engine."""
-        # runtime_s is an operator-facing wall timing, excluded from content
-        # keys and record equality  # staticcheck: allow(wall-clock)
-        t0 = time.perf_counter()
-        accelerator = self._materialize(arch)
-        engine = self.engine(workload, accelerator, granularity)
-        if self.tracer is not None:
-            engine.tracer = self.tracer
-        graph = engine.graph
-        feas = feasible_cores_per_layer(workload, accelerator)
+        from the exact engine. With a tracer, the whole exploration is one
+        `session.explore` span carrying the GA `seed`."""
+        tracer = NULL_TRACER if self.tracer is None else self.tracer
+        with tracer.span("session.explore", seed=seed):
+            # runtime_s is an operator-facing wall timing, excluded from
+            # content keys and record equality
+            # staticcheck: allow(wall-clock)
+            t0 = time.perf_counter()
+            accelerator = self._materialize(arch)
+            engine = self.engine(workload, accelerator, granularity)
+            if self.tracer is not None:
+                engine.tracer = self.tracer
+            graph = engine.graph
+            feas = feasible_cores_per_layer(workload, accelerator)
 
-        strict = granularity == "layer"  # traditional LBL: no overlap
-        canon = core_symmetry_canonicalize(accelerator)
+            strict = granularity == "layer"  # traditional LBL: no overlap
+            canon = core_symmetry_canonicalize(accelerator)
 
-        def evaluate_population(genomes: np.ndarray) -> np.ndarray:
-            # fitness only needs latency/energy: timing model without traces,
-            # resumed from the engine's shared segment-checkpoint store.
-            # Genomes are scheduled in canonical form (bit-identical by the
-            # identical-core symmetry backing the GA memo) so checkpoint
-            # prefixes are shared across each whole symmetry class.
-            if canon is not None:
-                genomes = np.stack([canon(g) for g in genomes])
-            return engine.evaluate_population(genomes, priority,
-                                              strict_layers=strict)
-
-        scalarize = {
-            "edp": lambda o: float(o[0] * o[1]),
-            "latency": lambda o: float(o[0]),
-            "energy": lambda o: float(o[1]),
-        }[objective]
-
-        if prefilter is None:
-            prefilter = self.prefilter
-        prefilter_fn = None
-        if prefilter:
-            from repro.core.vectorized import get_batched_fitness
-            bf = get_batched_fitness(engine, priority=priority,
-                                     strict_layers=strict)
-
-            def prefilter_fn(genomes: np.ndarray) -> np.ndarray:
-                # rank in canonical form so symmetry-equivalent genomes
-                # screen identically (mirrors the exact path above)
+            def evaluate_population(genomes: np.ndarray) -> np.ndarray:
+                # fitness only needs latency/energy: timing model without traces,
+                # resumed from the engine's shared segment-checkpoint store.
+                # Genomes are scheduled in canonical form (bit-identical by the
+                # identical-core symmetry backing the GA memo) so checkpoint
+                # prefixes are shared across each whole symmetry class.
                 if canon is not None:
                     genomes = np.stack([canon(g) for g in genomes])
-                return np.asarray(bf.scores(genomes))
+                return engine.evaluate_population(genomes, priority,
+                                                  strict_layers=strict)
 
-        if len(workload) == 1 or all(len(f) == 1 for f in feas):
-            alloc = np.array([f[0] for f in feas])
-            ga_res = None
-        else:
-            # dedup=False: stored sweep records are content-keyed under the
-            # promise that identical specs reproduce identical metrics, and
-            # the pre-existing stores were built with clone-keeping NSGA
-            # selection — union dedup changes survivor sets whenever clones
-            # occur, which would silently invalidate every persisted record
-            ga = GeneticAllocator(
-                n_genes=len(workload), feasible_cores=feas,
-                evaluate_population=evaluate_population,
-                pop_size=pop_size, generations=generations,
-                scalarize=scalarize, seed=seed,
-                cache_key=core_symmetry_cache_key(accelerator),
-                dedup=False,
-                prefilter=prefilter_fn,
-                prefilter_keep=self.prefilter_keep,
-                tracer=self.tracer,
+            scalarize = {
+                "edp": lambda o: float(o[0] * o[1]),
+                "latency": lambda o: float(o[0]),
+                "energy": lambda o: float(o[1]),
+            }[objective]
+
+            if prefilter is None:
+                prefilter = self.prefilter
+            prefilter_fn = None
+            if prefilter:
+                from repro.core.vectorized import get_batched_fitness
+                bf = get_batched_fitness(engine, priority=priority,
+                                         strict_layers=strict)
+
+                def prefilter_fn(genomes: np.ndarray) -> np.ndarray:
+                    # rank in canonical form so symmetry-equivalent genomes
+                    # screen identically (mirrors the exact path above)
+                    if canon is not None:
+                        genomes = np.stack([canon(g) for g in genomes])
+                    return np.asarray(bf.scores(genomes))
+
+            if len(workload) == 1 or all(len(f) == 1 for f in feas):
+                alloc = np.array([f[0] for f in feas])
+                ga_res = None
+            else:
+                # dedup=False: stored sweep records are content-keyed under the
+                # promise that identical specs reproduce identical metrics, and
+                # the pre-existing stores were built with clone-keeping NSGA
+                # selection — union dedup changes survivor sets whenever clones
+                # occur, which would silently invalidate every persisted record
+                ga = GeneticAllocator(
+                    n_genes=len(workload), feasible_cores=feas,
+                    evaluate_population=evaluate_population,
+                    pop_size=pop_size, generations=generations,
+                    scalarize=scalarize, seed=seed,
+                    cache_key=core_symmetry_cache_key(accelerator),
+                    dedup=False,
+                    prefilter=prefilter_fn,
+                    prefilter_keep=self.prefilter_keep,
+                    tracer=self.tracer,
+                )
+                ga_res = ga.run(initial=initial_allocations)
+                alloc = ga_res.best_genome
+
+            final = engine.schedule(alloc, priority, strict_layers=strict)
+            return StreamResult(
+                schedule=final, allocation=alloc, ga=ga_res, graph=graph,
+                # staticcheck: allow(wall-clock)
+                runtime_s=time.perf_counter() - t0,
+                granularity=granularity,
             )
-            ga_res = ga.run(initial=initial_allocations)
-            alloc = ga_res.best_genome
-
-        final = engine.schedule(alloc, priority, strict_layers=strict)
-        return StreamResult(
-            schedule=final, allocation=alloc, ga=ga_res, graph=graph,
-            runtime_s=time.perf_counter() - t0, granularity=granularity,  # staticcheck: allow(wall-clock)
-        )
 
     def evaluate_allocation(
         self,

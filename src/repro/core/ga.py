@@ -33,6 +33,7 @@ clones occur).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Callable, Sequence
 
@@ -83,6 +84,10 @@ def crowding_distance(objs: np.ndarray) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # GA driver
 # ---------------------------------------------------------------------------
+
+# the span a GeneticAllocator without a tracer opens: a reusable no-op
+_NO_SPAN = contextlib.nullcontext()
+
 
 @dataclasses.dataclass
 class GAResult:
@@ -187,11 +192,15 @@ class GeneticAllocator:
         self.prefilter_min_batch = int(prefilter_min_batch)
         self.prefilter_screened = 0
         self.prefilter_pruned = 0
-        # optional sim-time tracer (repro.obs): one span per generation on
-        # the generation-index clock plus counter deltas.  The tracer only
-        # observes the existing counters — search output is bit-identical
-        # with tracing on or off.
+        # optional tracer (repro.obs): a `ga.generation` span per generation
+        # (counter deltas as attributes) around `ga.variation`,
+        # `ga.prefilter`, `ga.exact` and `ga.select` spans.  The tracer only
+        # observes — search output is bit-identical with tracing on or off.
         self.tracer = tracer
+
+    def _span(self, name: str, **attrs):
+        tracer = self.tracer
+        return _NO_SPAN if tracer is None else tracer.span(name, **attrs)
 
     # ---- batched genome hashing / fitness memo -----------------------------
     def _keys(self, genomes: np.ndarray) -> list[bytes]:
@@ -220,13 +229,18 @@ class GeneticAllocator:
                 pending.add(k)
                 miss_rows.append(r)
                 miss_keys.append(k)
-        self.cache_hits += len(keys) - len(miss_rows)
+        hits = len(keys) - len(miss_rows)
+        self.cache_hits += hits
         if miss_rows:
-            vals = np.asarray(
-                self.evaluate_population_fn(genomes[miss_rows]), dtype=float)
+            with self._span("ga.exact"):
+                vals = np.asarray(self.evaluate_population_fn(
+                    genomes[miss_rows]), dtype=float)
             self.evaluations += len(miss_rows)
             for k, row in zip(miss_keys, vals):
                 cache[k] = tuple(float(x) for x in row)
+        if self.tracer is not None:
+            self.tracer.count("ga.evaluations", len(miss_rows))
+            self.tracer.count("ga.cache_hits", hits)
         return np.array([cache[k] for k in keys], dtype=float)
 
     def _eval(self, g: np.ndarray) -> tuple[float, ...]:
@@ -279,6 +293,8 @@ class GeneticAllocator:
             order.extend(front[np.argsort(-cd, kind="stable")].tolist())
         self.prefilter_screened += len(novel)
         self.prefilter_pruned += len(novel) - n_keep
+        if self.tracer is not None:
+            self.tracer.count("ga.prefilter_pruned", len(novel) - n_keep)
         keep = set(range(len(off))) - set(novel)
         keep |= {novel[i] for i in order[:n_keep]}
         return off[sorted(keep)]  # generation order preserved
@@ -295,71 +311,75 @@ class GeneticAllocator:
         stale = 0
         rng = self.rng
         for gen in range(self.generations):
-            if self.tracer is not None:
-                ev0, ch0 = self.evaluations, self.cache_hits
-                pf0 = self.prefilter_pruned
-            # ---- variation: tournament parents -> offspring -----------------
-            # scalarize once per generation, not once per tournament comparison
-            scal = [self.scalarize(o) for o in objs]
-            len_pop = len(pop)
-            off = np.empty((P, G), dtype=np.int64)
-            for k in range(P):
-                i, j = rng.integers(0, len_pop, size=2)
-                child = pop[i if scal[i] <= scal[j] else j].copy()
-                if rng.random() < self.crossover_p:
-                    # ordered (two-point segment) crossover
-                    mate = pop[int(rng.integers(len_pop))]
-                    a, b = sorted(rng.integers(0, G, size=2))
-                    child[a:b + 1] = mate[a:b + 1]
-                if rng.random() < self.mutation_p:
-                    self._mutate_inplace(child)
-                off[k] = child
-            if self.prefilter is not None:
-                off = self._prefilter_offspring(off)
-            # ---- NSGA-II environmental selection on parents+offspring -------
-            union = np.ascontiguousarray(np.concatenate([pop, off]))
-            ukeys = self._keys(union)
-            uobjs = self._eval_population(union, keys=ukeys)
-            if self.dedup:
-                # clones of one genome would enter the sort as duplicate rows
-                # (same front, zero crowding distance) and eat survivor slots
-                seen: set[bytes] = set()
-                keep = [r for r, k in enumerate(ukeys)
-                        if not (k in seen or seen.add(k))]
-                if len(keep) < len(ukeys):
-                    union = union[keep]
-                    uobjs = uobjs[keep]
-            fronts = fast_nondominated_sort(uobjs)
-            survivors: list[int] = []
-            for front in fronts:
-                if len(survivors) + front.size <= P:
-                    survivors.extend(front.tolist())
+            with self._span("ga.generation", gen=gen) as attrs:
+                if self.tracer is not None:
+                    ev0, ch0 = self.evaluations, self.cache_hits
+                    pf0 = self.prefilter_pruned
+                # ---- variation: tournament parents -> offspring -------------
+                with self._span("ga.variation"):
+                    # scalarize once per generation, not once per tournament
+                    # comparison
+                    scal = [self.scalarize(o) for o in objs]
+                    len_pop = len(pop)
+                    off = np.empty((P, G), dtype=np.int64)
+                    for k in range(P):
+                        i, j = rng.integers(0, len_pop, size=2)
+                        child = pop[i if scal[i] <= scal[j] else j].copy()
+                        if rng.random() < self.crossover_p:
+                            # ordered (two-point segment) crossover
+                            mate = pop[int(rng.integers(len_pop))]
+                            a, b = sorted(rng.integers(0, G, size=2))
+                            child[a:b + 1] = mate[a:b + 1]
+                        if rng.random() < self.mutation_p:
+                            self._mutate_inplace(child)
+                        off[k] = child
+                if self.prefilter is not None:
+                    with self._span("ga.prefilter"):
+                        off = self._prefilter_offspring(off)
+                # ---- NSGA-II environmental selection on parents+offspring ---
+                # (two `ga.select` spans around the union's exact evaluation,
+                # so `ga.exact` never nests in selection)
+                with self._span("ga.select"):
+                    union = np.ascontiguousarray(np.concatenate([pop, off]))
+                    ukeys = self._keys(union)
+                uobjs = self._eval_population(union, keys=ukeys)
+                with self._span("ga.select"):
+                    if self.dedup:
+                        # clones of one genome would enter the sort as
+                        # duplicate rows (same front, zero crowding distance)
+                        # and eat survivor slots
+                        seen: set[bytes] = set()
+                        keep = [r for r, k in enumerate(ukeys)
+                                if not (k in seen or seen.add(k))]
+                        if len(keep) < len(ukeys):
+                            union = union[keep]
+                            uobjs = uobjs[keep]
+                    fronts = fast_nondominated_sort(uobjs)
+                    survivors: list[int] = []
+                    for front in fronts:
+                        if len(survivors) + front.size <= P:
+                            survivors.extend(front.tolist())
+                        else:
+                            cd = crowding_distance(uobjs[front])
+                            order = front[np.argsort(-cd, kind="stable")]
+                            survivors.extend(
+                                order[: P - len(survivors)].tolist())
+                            break
+                    pop = np.ascontiguousarray(union[survivors])
+                    objs = uobjs[survivors]
+                    best = min(self.scalarize(o) for o in objs)
+                if history and best >= history[-1] - 1e-12:
+                    stale += 1
                 else:
-                    cd = crowding_distance(uobjs[front])
-                    order = front[np.argsort(-cd, kind="stable")]
-                    survivors.extend(order[: P - len(survivors)].tolist())
-                    break
-            pop = np.ascontiguousarray(union[survivors])
-            objs = uobjs[survivors]
-            best = min(self.scalarize(o) for o in objs)
-            if history and best >= history[-1] - 1e-12:
-                stale += 1
-            else:
-                stale = 0
-            history.append(best)
-            if self.tracer is not None:
-                d_ev = self.evaluations - ev0
-                d_ch = self.cache_hits - ch0
-                d_pf = self.prefilter_pruned - pf0
-                self.tracer.add_span(
-                    "ga.generation", float(gen), float(gen + 1),
-                    evaluations=d_ev, cache_hits=d_ch,
-                    prefilter_pruned=d_pf, best=best)
-                self.tracer.count("ga.generations")
-                self.tracer.count("ga.evaluations", d_ev)
-                self.tracer.count("ga.cache_hits", d_ch)
-                self.tracer.count("ga.prefilter_pruned", d_pf)
-                self.tracer.observe("ga.best", best)
+                    stale = 0
+                history.append(best)
+                if self.tracer is not None:
+                    d_ev = self.evaluations - ev0
+                    d_ch = self.cache_hits - ch0
+                    d_pf = self.prefilter_pruned - pf0
+                    attrs.update(evaluations=d_ev, cache_hits=d_ch,
+                                 prefilter_pruned=d_pf, best=best)
+                    self.tracer.count("ga.generations")
             if stale >= self.patience:  # "after the desired metric saturates"
                 break
         # ---- results -------------------------------------------------------

@@ -168,8 +168,8 @@ class ScheduleEngine:
         self.cost_model = cost_model
         self.accelerator = acc
         self.n = graph.n
-        # optional sim-time tracer (repro.obs); None keeps schedule() free
-        # of any tracing overhead beyond one attribute read per call
+        # optional tracer (repro.obs): an `engine.schedule` span and CN
+        # counters per schedule; None costs one attribute read per call
         self.tracer = None
         tables = cost_model.precompute(graph, acc)
         self.tables = tables
@@ -369,6 +369,17 @@ class ScheduleEngine:
             >>> engine.evaluate(alloc) == (res.latency_cc, res.energy_pj)
             True
         """
+        tracer = self.tracer
+        if tracer is None:
+            return self._schedule(allocation, priority, segment,
+                                  strict_layers, record, checkpoint, validate)
+        with tracer.span("engine.schedule"):
+            return self._schedule(allocation, priority, segment,
+                                  strict_layers, record, checkpoint, validate)
+
+    def _schedule(self, allocation, priority, segment, strict_layers,
+                  record, checkpoint, validate) -> ScheduleResult:
+        """The body of `schedule`, which holds its tracer span."""
         if priority not in ("latency", "memory"):
             raise ValueError(f"unknown priority {priority!r}")
         acc = self.accelerator
@@ -814,12 +825,11 @@ class ScheduleEngine:
         )
         tracer = self.tracer
         if tracer is not None:
-            # sim-time channel: counters/histograms only (bounded memory per
-            # GA run); the tracer observes, it never steers the schedule.
+            # counted for every schedule, checkpointed or not: CN work is
+            # counted, not timed (one span per schedule, none per CN)
             tracer.count("engine.schedules")
-            tracer.count("engine.cns", n)
-            tracer.observe("engine.latency_cc", result.latency_cc)
-            tracer.observe("engine.energy_pj", result.energy_pj)
+            tracer.count("engine.cns_walked", n - n_resumed)
+            tracer.count("engine.cns_resumed", n_resumed)
         if validate:
             if not record:
                 raise ValueError("validate=True needs record=True "

@@ -35,6 +35,7 @@ traffic): it never exceeds the exact latency beyond float rounding.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 import numpy as np
@@ -43,6 +44,7 @@ BIG = 1e30      # cycles stand-in for infeasible (CN, core) pairs
 NEG = -1e30     # release-time stand-in for "not queued on this resource"
 
 _OBJECTIVES = ("edp", "latency", "energy")
+_NO_SPAN = contextlib.nullcontext()    # `scores` without a tracer
 
 
 def rank_correlation(a, b) -> float:
@@ -660,7 +662,18 @@ class BatchedFitness:
 
     def scores(self, genomes) -> np.ndarray:
         """Approximate `(K, 2)` `[latency_cc, energy_pj]` for `(K, G)`
-        genomes. Values rank; they are not the engine's exact metrics."""
+        genomes. Values rank; they are not the engine's exact metrics.
+
+        With a tracer on the engine, the call is a `fitness.scores` span and
+        each blocking fetch of a chunk's results a `fitness.wait` span in
+        it, so host preparation and the wait for the device come apart."""
+        tracer = self.engine.tracer
+        if tracer is None:
+            return self._scores(genomes, None)
+        with tracer.span("fitness.scores"):
+            return self._scores(genomes, tracer)
+
+    def _scores(self, genomes, tracer) -> np.ndarray:
         import jax.numpy as jnp
         g = self._as_matrix(genomes)
         k = g.shape[0]
@@ -673,8 +686,10 @@ class BatchedFitness:
                 part = np.concatenate(
                     [part, np.repeat(part[-1:], chunk - m, axis=0)])
             lat, en = self._score_fn(jnp.asarray(part, dtype=jnp.int32))
-            out[o:o + m, 0] = np.asarray(lat, dtype=np.float64)[:m]
-            out[o:o + m, 1] = np.asarray(en, dtype=np.float64)[:m]
+            with (_NO_SPAN if tracer is None
+                  else tracer.span("fitness.wait")):
+                out[o:o + m, 0] = np.asarray(lat, dtype=np.float64)[:m]
+                out[o:o + m, 1] = np.asarray(en, dtype=np.float64)[:m]
         return out
 
     def scalar_scores(self, genomes, objective: str = "edp") -> np.ndarray:

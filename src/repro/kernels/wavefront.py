@@ -84,6 +84,7 @@ def serialize_prefix(free0, release, dur, *, block_rows: int = 128,
         out_shape=[jax.ShapeDtypeStruct(rel.shape, rel.dtype),
                    jax.ShapeDtypeStruct((rel.shape[0], 1), rel.dtype)],
         interpret=interpret,
+        name="wavefront_serialize",
     )(fr, rel, d)
     if pad:
         fin, free = fin[:rows], free[:rows]

@@ -8,7 +8,9 @@ Two strictly separated channels (docs/ARCHITECTURE.md §13):
   Pure functions of recorded state: byte-identical across runs, pinned to
   the ``deterministic`` staticcheck tier.
 * **wall-time** — `repro.obs.realtime.wall_tracer`, the only wall-clock
-  entry point, pinned REALTIME and confined to operator-facing output.
+  entry point, pinned REALTIME: the same spans in `time.perf_counter`
+  seconds, mirrored as profiler annotations, for operators and the chip
+  benchmark's per-layer readings, never for content-keyed records.
 
     >>> from repro.obs import Tracer
     >>> tr = Tracer()

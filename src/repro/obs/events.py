@@ -1,13 +1,14 @@
-"""Sim-time observability primitives: span events, histograms, sinks.
+"""Observability primitives: span events, histograms, sinks.
 
-Everything in this module lives on the *sim-time* channel of the repo's
-two-channel observability design (docs/ARCHITECTURE.md §13): timestamps
-are logical ticks or simulated cycles — pure functions of schedule or
-serving state — never the wall clock, so recorded events and metric
-snapshots are byte-identical across runs and safe for the
-``deterministic`` staticcheck tier.  The only wall-time entry point of
-the package is `repro.obs.realtime`, which is pinned to the REALTIME
-tier and never feeds content-keyed records.
+Nothing in this module reads a clock: a `SpanEvent` carries whatever
+times its `Tracer` gave it.  On the *sim-time* channel of the repo's
+two-channel design (docs/ARCHITECTURE.md §13) those are logical ticks or
+simulated cycles — pure functions of schedule or serving state — so
+recorded events and metric snapshots are byte-identical across runs and
+safe for the ``deterministic`` staticcheck tier.  Under
+`repro.obs.realtime.wall_tracer` the same events carry wall seconds: the
+chip benchmark's per-layer readers of the GA and the exact scheduler
+read them, and the profiler's trace shows them as annotations.  Wall events never feed content-keyed records.
 
     >>> sink = InMemorySink()
     >>> sink.emit(SpanEvent(name="ga.generation", t0=0.0, t1=1.0, depth=0,
@@ -28,7 +29,8 @@ class SpanEvent:
 
     The time unit is whatever clock the recording `Tracer` runs on —
     logical ticks by default, simulated cycles when the caller passes
-    explicit times, wall seconds only under `repro.obs.realtime`.
+    explicit times, `time.perf_counter` seconds under
+    `repro.obs.realtime.wall_tracer`.
 
         >>> ev = SpanEvent("schedule", 0.0, 128.0, 0, {"cns": 64})
         >>> ev.duration, ev.to_dict()["name"]

@@ -1,14 +1,25 @@
-"""Wall-time observability sink — the REALTIME half of the two-channel split.
+"""Wall-time observability — the REALTIME half of the two-channel split.
 
 This is the only module of `repro.obs` allowed to read the wall clock
 (it is pinned REALTIME in `repro.analysis.staticcheck.tiers`, so the
-linter's wall-clock rule does not apply here).  Wall-time spans wrap
-*real execution* — worker wall time, store I/O, pool dispatch — and are
-strictly for operator eyes: nothing recorded through a wall tracer may
-reach content-keyed records, golden traces, or BENCH metric values.
-Everything deterministic stays on the sim-time channel
-(`repro.obs.tracing` with the default logical clock or explicit
-simulated-cycle spans).
+linter's wall-clock rule does not apply here).  A wall tracer times the
+same spans the program opens through `Tracer.span` on any clock —
+`session.explore`, the GA's `ga.generation` and its `ga.variation`,
+`ga.prefilter`, `ga.exact` and `ga.select` children, `fitness.scores`
+and `fitness.wait`, `engine.schedule` — in `time.perf_counter` seconds,
+the clock the chip benchmark's window and trace mapping read: its
+per-layer readers of the GA and the exact scheduler read these spans
+and counters, and the spans can name the device's idle gaps.  Each span
+also opens a `jax.profiler.TraceAnnotation` of its name, so a profile
+taken while it runs (TensorBoard, Perfetto) shows the spans on the host
+thread on the profiler's own clock; with no profiler session the
+annotation does nothing.  jax is imported when a wall tracer is built,
+never at this module's import.
+
+Wall spans are machine-dependent: nothing recorded through a wall tracer
+may reach content-keyed records or golden traces.  Everything
+deterministic stays on the sim-time channel (`repro.obs.tracing` with
+the default logical clock or explicit simulated-cycle spans).
 
     >>> tr = wall_tracer()
     >>> with tr.span("io"):
@@ -35,15 +46,18 @@ def wall_clock() -> float:
 
 
 def wall_tracer(sink: Sink | None = None) -> Tracer:
-    """A `Tracer` whose clock is the monotonic wall clock.
+    """A `Tracer` on the monotonic wall clock whose spans are mirrored as
+    `jax.profiler.TraceAnnotation`s of the same names.
 
     Spans from a wall tracer measure real elapsed seconds and are
-    therefore machine-dependent; confine their output to logs and
-    dashboards, never to content-keyed stores.
+    therefore machine-dependent; confine their output to logs,
+    dashboards and benchmark readings, never to content-keyed stores.
 
         >>> tr = wall_tracer()
         >>> tr.count("pool.dispatch")
         >>> tr.snapshot()["counters"]
         {'pool.dispatch': 1.0}
     """
-    return Tracer(sink=sink, clock=time.perf_counter)
+    from jax.profiler import TraceAnnotation
+    return Tracer(sink=sink, clock=time.perf_counter,
+                  annotate=TraceAnnotation)

@@ -4,10 +4,13 @@ A tracer binds a clock to a `MetricsRegistry` and a span `Sink`.  The
 default clock is a *logical tick counter* — each clock read returns the
 next integer — so code instrumented on the sim-time channel (GA
 generations, sweep points, engine schedules) records byte-identical
-traces on every run.  Callers that already know their interval in
-simulated cycles record it with `add_span(name, t0, t1)`; only
-`repro.obs.realtime.wall_tracer` ever installs a wall clock, and that
-module is pinned to the REALTIME staticcheck tier.
+traces on every run.  Every span of running code opens and closes
+through `span`, so the same call sites record wall seconds under
+`repro.obs.realtime.wall_tracer` (the only place a wall clock, and the
+profiler annotation that mirrors each span, is installed; that module is
+pinned to the REALTIME staticcheck tier).  `add_span(name, t0, t1)` is
+for callers that hold an interval of simulated cycles: the trace
+exporter and the serving simulator.
 
 Disabled tracing is free: instrumented call sites hold a tracer
 attribute that defaults to None and guard every use with
@@ -29,7 +32,7 @@ tracer observes, it never steers.
 from __future__ import annotations
 
 from contextlib import contextmanager
-from typing import Callable
+from typing import Callable, ContextManager
 
 from repro.obs.events import InMemorySink, MetricsRegistry, Sink, SpanEvent
 
@@ -39,7 +42,9 @@ class Tracer:
 
     `clock=None` (the default) installs the logical tick counter; pass a
     callable returning floats to trace another time base.  `sink=None`
-    installs an `InMemorySink`, exposed through `events`.
+    installs an `InMemorySink`, exposed through `events`.  `annotate`,
+    when given, maps a span's name to a context manager entered for the
+    span's lifetime (the wall tracer's profiler annotation).
 
         >>> tr = Tracer()
         >>> with tr.span("outer"):
@@ -53,9 +58,11 @@ class Tracer:
     """
 
     def __init__(self, sink: Sink | None = None,
-                 clock: Callable[[], float] | None = None):
+                 clock: Callable[[], float] | None = None,
+                 annotate: Callable[[str], ContextManager] | None = None):
         self.sink = InMemorySink() if sink is None else sink
         self._clock = clock
+        self._annotate = annotate
         self._tick = 0
         self._depth = 0
         self.metrics = MetricsRegistry()
@@ -79,25 +86,34 @@ class Tracer:
     def span(self, name: str, **attrs):
         """Context manager recording one nested span (closed on exit —
         exits by exception included, so traces never hold open spans).
+        It yields the span's attribute dict, so the body can add values
+        it only knows at the end.
 
             >>> tr = Tracer()
-            >>> with tr.span("step", point="k0"):
-            ...     pass
+            >>> with tr.span("step", point="k0") as attrs:
+            ...     attrs["rows"] = 8
             >>> tr.events[0].attrs
-            {'point': 'k0'}
+            {'point': 'k0', 'rows': 8}
         """
+        note = None if self._annotate is None else self._annotate(name)
+        if note is not None:
+            note.__enter__()
         t0 = self.now()
         depth = self._depth
         self._depth = depth + 1
         try:
-            yield self
+            yield attrs
         finally:
+            t1 = self.now()
+            if note is not None:
+                note.__exit__(None, None, None)
             self._depth = depth
-            self.sink.emit(SpanEvent(name=name, t0=t0, t1=self.now(),
+            self.sink.emit(SpanEvent(name=name, t0=t0, t1=t1,
                                      depth=depth, attrs=attrs))
 
     def add_span(self, name: str, t0: float, t1: float, **attrs) -> None:
-        """Record an already-timed interval (e.g. simulated cycles)."""
+        """Record an already-timed interval of simulated cycles (the
+        exporter, the serving simulator); running code uses `span`."""
         self.sink.emit(SpanEvent(name=name, t0=float(t0), t1=float(t1),
                                  depth=self._depth, attrs=attrs))
 
@@ -137,7 +153,7 @@ class NullTracer:
         __slots__ = ()
 
         def __enter__(self):
-            return None
+            return {}
 
         def __exit__(self, *exc):
             return False

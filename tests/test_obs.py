@@ -12,7 +12,7 @@ import pytest
 
 from repro.api import (DesignSpace, ExplorationSession, FaultInjector,
                        GAConfig, HeartbeatMonitor, build_manifest, run_shard)
-from repro.configs.paper_workloads import fsrcnn
+from repro.configs.paper_workloads import fsrcnn, squeezenet
 from repro.core import CostModel, build_graph
 from repro.core.allocator import manual_pingpong
 from repro.core.scheduler import ScheduleEngine
@@ -23,6 +23,7 @@ from repro.obs import (NULL_TRACER, InMemorySink, JsonlSink, Tracer,
                        schedule_trace_events, serving_trace_events,
                        trace_schedule, validate_trace_events,
                        write_chrome_trace)
+from repro.obs.realtime import wall_tracer
 from repro.serve.arrivals import poisson_trace
 from repro.serve.simulator import PhaseCosts, simulate
 
@@ -278,9 +279,11 @@ def _content(record) -> dict:
     return d
 
 
-def test_tracing_keeps_records_bit_identical():
+@pytest.mark.parametrize("make_tracer", [Tracer, wall_tracer],
+                         ids=["logical", "wall"])
+def test_tracing_keeps_records_bit_identical(make_tracer):
     plain = ExplorationSession().run(_space())
-    tr = Tracer()
+    tr = make_tracer()
     traced = ExplorationSession(tracer=tr).run(_space())
     assert [_content(r) for r in plain.records] == \
         [_content(r) for r in traced.records]
@@ -291,13 +294,32 @@ def test_tracing_keeps_records_bit_identical():
 
 
 def test_ga_generation_spans_and_store_hit_counter(tmp_path):
-    tr = Tracer()
+    # a logical clock that keeps the counters as they stood at each read,
+    # so each span's two ends give the counter deltas over it
+    at: list[dict] = []
+
+    def clock():
+        at.append(dict(tr.metrics.counters))
+        return float(len(at) - 1)
+    tr = Tracer(clock=clock)
     sess = ExplorationSession(cache_dir=str(tmp_path), tracer=tr)
     sess.run(_space())
     gens = [e for e in tr.events if e.name == "ga.generation"]
-    assert gens and all(e.t1 == e.t0 + 1.0 for e in gens)
-    assert all(e.attrs["evaluations"] >= 0 and "best" in e.attrs
-               for e in gens)
+    assert gens
+    for g in gens:
+        children = [e for e in tr.events if e.depth == g.depth + 1
+                    and g.t0 < e.t0 < g.t1]
+        assert children and all(e.t1 < g.t1 for e in children)
+        assert {e.name for e in children} <= {
+            "ga.variation", "ga.prefilter", "ga.exact", "ga.select"}
+        assert [e.name for e in children].count("ga.variation") == 1
+        assert [e.name for e in children].count("ga.select") == 2
+        before, after = at[int(g.t0)], at[int(g.t1)]
+        for attr in ("evaluations", "cache_hits", "prefilter_pruned"):
+            name = f"ga.{attr}"
+            assert g.attrs[attr] == after.get(name, 0) - before.get(name, 0)
+        assert after["ga.generations"] - before.get("ga.generations", 0) == 1
+        assert "best" in g.attrs
     before = tr.snapshot()["counters"].get("sweep.store_hits", 0)
     sweep2 = sess.run(_space())   # warm store: all points served from disk
     after = tr.snapshot()["counters"]["sweep.store_hits"]
@@ -306,6 +328,113 @@ def test_ga_generation_spans_and_store_hit_counter(tmp_path):
     assert snap["store_records"] == len(sweep2.records)
     assert snap["store_failures"] == 0
     assert snap["sweep.store_hits"] == after
+
+
+# ---------------------------------------------------------------------------
+# wall spans of one exploration: the tree, the counters, the profiler
+# ---------------------------------------------------------------------------
+
+# each span's possible parents (None: a root)
+_PARENTS = {"session.explore": {None},
+            "ga.generation": {"session.explore"},
+            "ga.exact": {"session.explore", "ga.generation"},
+            "ga.variation": {"ga.generation"},
+            "ga.prefilter": {"ga.generation"},
+            "ga.select": {"ga.generation"},
+            "fitness.scores": {"ga.prefilter"},
+            "fitness.wait": {"fitness.scores"},
+            "engine.schedule": {"ga.exact", "session.explore"}}
+
+
+def _explore_traced(tracer, seeds=(0,)):
+    """Prefiltered explorations of squeezenet on MC:HomTPU, whose fused
+    stacks give the engine checkpoints to resume from."""
+    w, acc, gran = squeezenet(), mc_hom_tpu(), ("tile", 16, 1)
+    sess = ExplorationSession(prefilter=True, prefilter_keep=0.5,
+                              tracer=tracer)
+    results = [sess.explore(w, acc, granularity=gran, pop_size=16,
+                            generations=3, seed=seed) for seed in seeds]
+    return sess, sess.engine(w, acc, gran), results
+
+
+def _children(events) -> dict:
+    """Index of each span's parent (None: a root) -> its children's
+    indices, in the order they closed: spans of one depth are disjoint."""
+    kids: dict = {}
+    for i, e in enumerate(events):
+        (p,) = [j for j, q in enumerate(events) if q.depth == e.depth - 1
+                and q.t0 <= e.t0 and e.t1 <= q.t1] or [None]
+        assert (events[p].name if p is not None else None) in \
+            _PARENTS[e.name], e
+        kids.setdefault(p, []).append(i)
+    return kids
+
+
+def test_wall_spans_nest_per_generation():
+    tr = wall_tracer()
+    _, _, (res,) = _explore_traced(tr)
+    ev = tr.events
+    assert {e.name for e in ev} == set(_PARENTS)
+    kids = _children(ev)
+    (root,) = kids[None]
+    assert ev[root].attrs == {"seed": 0}
+    gens = [i for i in kids[root] if ev[i].name == "ga.generation"]
+    assert len(gens) == len(res.ga.history)
+    assert [ev[i].name for i in kids[root]] == (
+        ["ga.exact"] + ["ga.generation"] * len(gens) + ["engine.schedule"])
+    for g in gens:
+        names = [ev[i].name for i in kids[g]]
+        assert names[0] == "ga.variation" and names.count("ga.select") == 2
+        scheduled = [k for i in kids[g] if ev[i].name == "ga.exact"
+                     for k in kids[i]]
+        assert len(scheduled) == ev[g].attrs["evaluations"]
+    screens = [i for i, e in enumerate(ev) if e.name == "fitness.scores"]
+    assert screens and all(
+        kids[i] and {ev[k].name for k in kids[i]} == {"fitness.wait"}
+        for i in screens)
+
+
+def test_counters_equal_the_ga_and_engine_counts():
+    tr = Tracer()
+    sess, engine, results = _explore_traced(tr, seeds=(0, 1))
+    c = tr.snapshot()["counters"]
+    for attr in ("evaluations", "cache_hits", "prefilter_pruned"):
+        assert c[f"ga.{attr}"] == sum(getattr(r.ga, attr) for r in results)
+    assert c["ga.prefilter_pruned"] > 0
+    # every GA evaluation schedules one genome, plus one final schedule per
+    # exploration, which records its trace and so is not checkpointed
+    assert c["engine.schedules"] == c["ga.evaluations"] + len(results)
+    ck = engine.ckpt_stats
+    assert c["engine.cns_resumed"] == ck["cns_skipped"] > 0
+    assert c["engine.cns_walked"] == \
+        ck["cns_scheduled"] + engine.n * len(results)
+    assert "engine.cns" not in c and tr.snapshot()["histograms"] == {}
+    # on checkpointed schedules alone the counters are the engine's own
+    engine.reset_checkpoints()
+    tr2 = engine.tracer = Tracer()
+    engine.evaluate_population(results[0].ga.pareto_genomes)
+    c2 = tr2.snapshot()["counters"]
+    assert c2["engine.cns_walked"] == engine.ckpt_stats["cns_scheduled"]
+    assert c2["engine.cns_resumed"] == engine.ckpt_stats["cns_skipped"]
+
+
+def test_wall_spans_are_profiler_annotations(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    tr = wall_tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _explore_traced(tr)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    lines = [[e.name for e in line.events]
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    (line,) = [names for names in lines if "ga.generation" in names]
+    for name in ("ga.generation", "ga.variation", "ga.select",
+                 "ga.prefilter", "ga.exact", "engine.schedule"):
+        assert line.count(name) == sum(e.name == name for e in tr.events)
 
 
 # ---------------------------------------------------------------------------
