@@ -44,8 +44,8 @@ from repro.core.costmodel import CostModel
 from repro.core.depgraph import CNGraph, build_cn_graph
 from repro.core.ga import GeneticAllocator
 from repro.core.scheduler import ScheduleEngine, ScheduleResult, get_engine
-from repro.core.stream_api import StreamResult, core_symmetry_cache_key, \
-    core_symmetry_canonicalize, hw_min_tiles
+from repro.core.stream_api import StreamResult, core_symmetry_canonicalize, \
+    hw_min_tiles
 from repro.core.workload import Workload
 from repro.hw.accelerator import Accelerator
 from repro.obs.tracing import NULL_TRACER
@@ -1139,15 +1139,20 @@ class ExplorationSession:
             strict = granularity == "layer"  # traditional LBL: no overlap
             canon = core_symmetry_canonicalize(accelerator)
 
+            def canonical(genomes: np.ndarray) -> np.ndarray:
+                if canon is None:
+                    return genomes
+                tracer.count("ga.canonical_rows", len(genomes))
+                tracer.count("ga.canonical_calls")
+                return canon(genomes)
+
             def evaluate_population(genomes: np.ndarray) -> np.ndarray:
                 # fitness only needs latency/energy: timing model without traces,
                 # resumed from the engine's shared segment-checkpoint store.
                 # Genomes are scheduled in canonical form (bit-identical by the
                 # identical-core symmetry backing the GA memo) so checkpoint
                 # prefixes are shared across each whole symmetry class.
-                if canon is not None:
-                    genomes = np.stack([canon(g) for g in genomes])
-                return engine.evaluate_population(genomes, priority,
+                return engine.evaluate_population(canonical(genomes), priority,
                                                   strict_layers=strict)
 
             scalarize = {
@@ -1167,9 +1172,7 @@ class ExplorationSession:
                 def prefilter_fn(genomes: np.ndarray) -> np.ndarray:
                     # rank in canonical form so symmetry-equivalent genomes
                     # screen identically (mirrors the exact path above)
-                    if canon is not None:
-                        genomes = np.stack([canon(g) for g in genomes])
-                    return np.asarray(bf.scores(genomes))
+                    return np.asarray(bf.scores(canonical(genomes)))
 
             if len(workload) == 1 or all(len(f) == 1 for f in feas):
                 alloc = np.array([f[0] for f in feas])
@@ -1185,7 +1188,7 @@ class ExplorationSession:
                     evaluate_population=evaluate_population,
                     pop_size=pop_size, generations=generations,
                     scalarize=scalarize, seed=seed,
-                    cache_key=core_symmetry_cache_key(accelerator),
+                    canonicalize=canon,
                     dedup=False,
                     prefilter=prefilter_fn,
                     prefilter_keep=self.prefilter_keep,

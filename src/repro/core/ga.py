@@ -11,12 +11,14 @@ surviving individuals over the Pareto front.
 The allocator is population-native: the population lives as a `(P, G)` int64
 matrix, fitness is requested through `evaluate_population(genomes) -> (P, M)`
 (a per-genome `evaluate` callable is accepted and adapted), cache keys are
-hashed for the whole batch at once, and only the cache-missing unique rows
-of each generation reach the evaluator — which can then exploit shared
-allocation prefixes across the batch (see `ScheduleEngine.
-evaluate_population`). The `pop + offspring` union is deduplicated by cache
-key before environmental selection, so identical genomes cannot inflate the
-fronts and waste crowding-distance slots on copies.
+hashed for the whole batch at once (an optional `canonicalize` first maps
+the whole `(K, G)` matrix to its symmetry-canonical form in one call), and
+only the cache-missing unique rows of each generation reach the evaluator —
+which can then exploit shared allocation prefixes across the batch (see
+`ScheduleEngine.evaluate_population`). The `pop + offspring` union is
+deduplicated by cache key before environmental selection, so identical
+genomes cannot inflate the fronts and waste crowding-distance slots on
+copies.
 
 An optional approximate-fitness `prefilter` (see `repro.core.vectorized.
 BatchedFitness`) screens each generation's novel offspring: it ranks them by
@@ -118,6 +120,9 @@ class GeneticAllocator:
     Pass per-genome `evaluate` (tuple of minimized objectives) or batched
     `evaluate_population` ((K, G) matrix -> (K, M) objectives); `run()`
     returns the best genome under `scalarize` plus the final Pareto front.
+    `canonicalize` ((K, G) matrix -> (K, G) int64 canonical matrix, e.g.
+    `core_symmetry_canonicalize`) makes genomes equivalent under a
+    fitness-preserving symmetry share one memo entry.
 
         >>> import numpy as np
         >>> ga = GeneticAllocator(
@@ -145,7 +150,7 @@ class GeneticAllocator:
         scalarize: Callable[[np.ndarray], float] | None = None,
         seed: int = 0,
         patience: int = 8,
-        cache_key: Callable[[np.ndarray], bytes] | None = None,
+        canonicalize: Callable[[np.ndarray], np.ndarray] | None = None,
         dedup: bool = True,
         prefilter: Callable[[np.ndarray], np.ndarray] | None = None,
         prefilter_keep: float = 0.75,
@@ -171,10 +176,11 @@ class GeneticAllocator:
         self.scalarize = scalarize or (lambda o: float(np.prod(o)))
         self.rng = np.random.default_rng(seed)
         self.patience = patience
-        # memo key; callers may pass a canonicalizer that maps genomes
-        # equivalent under a fitness-preserving symmetry (e.g. permutations
-        # of identical cores) to one key, deduplicating their evaluations
-        self.cache_key = cache_key
+        # memo key: the row's int64 bytes; callers may pass a batch
+        # canonicalizer that maps genomes equivalent under a
+        # fitness-preserving symmetry (e.g. permutations of identical cores)
+        # to one row, deduplicating their evaluations
+        self.canonicalize = canonicalize
         self._cache: dict[bytes, tuple[float, ...]] = {}
         self.evaluations = 0
         self.queries = 0
@@ -194,7 +200,9 @@ class GeneticAllocator:
         self.prefilter_pruned = 0
         # optional tracer (repro.obs): a `ga.generation` span per generation
         # (counter deltas as attributes) around `ga.variation`,
-        # `ga.prefilter`, `ga.exact` and `ga.select` spans.  The tracer only
+        # `ga.prefilter`, `ga.exact` and `ga.select` spans, and counters
+        # `ga.canonical_rows` / `ga.canonical_calls` per canonicalized
+        # batch (rows per call shows the batching).  The tracer only
         # observes — search output is bit-identical with tracing on or off.
         self.tracer = tracer
 
@@ -204,10 +212,14 @@ class GeneticAllocator:
 
     # ---- batched genome hashing / fitness memo -----------------------------
     def _keys(self, genomes: np.ndarray) -> list[bytes]:
-        """Cache key per row of a (K, G) genome matrix, hashed as one buffer
-        when no symmetry canonicalizer is installed."""
-        if self.cache_key is not None:
-            return [self.cache_key(g) for g in genomes]
+        """Cache key per row of a (K, G) genome matrix: slices of one buffer,
+        the matrix canonicalized in one call when a canonicalizer is
+        installed."""
+        if self.canonicalize is not None:
+            if self.tracer is not None:
+                self.tracer.count("ga.canonical_rows", len(genomes))
+                self.tracer.count("ga.canonical_calls")
+            genomes = self.canonicalize(genomes)
         buf = genomes.tobytes()
         step = genomes.shape[1] * genomes.itemsize
         return [buf[o:o + step] for o in range(0, len(buf), step)]

@@ -39,7 +39,15 @@ def core_symmetry_canonicalize(accelerator: Accelerator):
     stable*: the canonical form of a genome prefix depends only on that
     prefix, so GA offspring share canonical allocation prefixes with their
     parents and the scheduler's segment checkpoints hit across the whole
-    symmetry class. Returns None when every core is unique.
+    symmetry class. Returns None when every core is unique; otherwise a
+    function that canonicalizes a whole `(K, G)` genome matrix in one
+    vectorized pass (a `(G,)` genome or a list also works), returning int64
+    of the input's shape.
+
+        >>> from repro.hw.catalog import mc_hom_tpu
+        >>> canon = core_symmetry_canonicalize(mc_hom_tpu())
+        >>> canon([[3, 2, 3, 4], [1, 1, 0, 2]]).tolist()
+        [[0, 1, 0, 4], [0, 0, 1, 2]]
 
     Cores are grouped by their *content* — the `name` label cannot affect
     any cost or capacity, so "tpu0" and "tpu1" with equal specs are one
@@ -57,41 +65,32 @@ def core_symmetry_canonicalize(accelerator: Accelerator):
     for i, c in enumerate(accelerator.cores):
         groups.setdefault((cluster_of[i], dataclasses.replace(c, name="")),
                           []).append(i)
-    sym = {i: tuple(members) for members in
-           (m for m in groups.values() if len(m) > 1) for i in members}
-    if not sym:
+    sym_groups = [np.array(m, dtype=np.int64) for m in groups.values()
+                  if len(m) > 1]
+    if not sym_groups:
         return None
 
-    def canonicalize(genome) -> np.ndarray:
-        remap: dict[int, int] = {}
-        next_slot: dict[tuple, int] = {}
-        out = np.empty(len(genome), dtype=np.int64)
-        for idx, g in enumerate(genome):
-            g = int(g)
-            members = sym.get(g)
-            if members is not None:
-                m = remap.get(g)
-                if m is None:
-                    k = next_slot.get(members, 0)
-                    m = members[k]
-                    next_slot[members] = k + 1
-                    remap[g] = m
-                g = m
-            out[idx] = g
-        return out
+    identity = np.arange(accelerator.n_cores, dtype=np.int64)
+
+    def canonicalize(genomes) -> np.ndarray:
+        """Canonical form of a (K, G) genome matrix, a (G,) genome or a
+        list of core ids, in one pass over the whole batch: within each
+        group, the members are ranked by the column of their first
+        appearance in the row (G when absent), and member i is relabeled
+        members[rank of i] through a per-row table."""
+        a = np.asarray(genomes, dtype=np.int64)
+        rows = np.atleast_2d(a)
+        n_rows, n_genes = rows.shape
+        table = np.tile(identity, (n_rows, 1))  # (K, n_cores) relabeling
+        for members in sym_groups:
+            eq = rows == members[:, None, None]  # (k, K, G)
+            first = np.where(eq.any(axis=2), eq.argmax(axis=2), n_genes)
+            rank = np.argsort(np.argsort(first, axis=0, kind="stable"),
+                              axis=0)
+            table[:, members] = members[rank].T
+        return np.take_along_axis(table, rows, axis=1).reshape(a.shape)
 
     return canonicalize
-
-
-def core_symmetry_cache_key(accelerator: Accelerator):
-    """Genome-memo key: byte string of the canonical form (see
-    `core_symmetry_canonicalize`), so genomes equivalent under identical-core
-    permutations share one GA cache entry. Returns None when every core is
-    unique (no symmetry to exploit)."""
-    canon = core_symmetry_canonicalize(accelerator)
-    if canon is None:
-        return None
-    return lambda genome: canon(genome).tobytes()
 
 
 def hw_min_tiles(accelerator: Accelerator) -> dict[str, int]:
