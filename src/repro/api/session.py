@@ -1075,14 +1075,19 @@ class ExplorationSession:
 
     def graph(self, workload: Workload, arch: "ArchSpec | Accelerator",
               granularity, use_rtree: bool = True) -> CNGraph:
-        """CN graph for (workload content, granularity, HW min tiles)."""
+        """CN graph for (workload content, granularity, HW min tiles).
+
+        With a tracer, building one (CN identification and edges) is a
+        `cn.graph` span; a cached graph opens none."""
         accelerator = self._materialize(arch)
         min_tile = hw_min_tiles(accelerator)
         key = (_graph_key(workload, granularity, min_tile), use_rtree)
         graph = self._graphs.get(key)
         if graph is None:
-            cns = identify_cns(workload, granularity, min_tile)
-            graph = build_cn_graph(workload, cns, use_rtree=use_rtree)
+            tracer = NULL_TRACER if self.tracer is None else self.tracer
+            with tracer.span("cn.graph"):
+                cns = identify_cns(workload, granularity, min_tile)
+                graph = build_cn_graph(workload, cns, use_rtree=use_rtree)
             self._graphs.put(key, graph)
         return graph
 

@@ -214,6 +214,18 @@ def resnet18_first_segment() -> Workload:
     return w
 
 
+def deepseek_v2_lite_prefill() -> Workload:
+    """DeepSeek-V2-Lite's prefill of one 4096-token prompt (its
+    `original_max_position_embeddings`, the longest before YaRN scaling)
+    at batch 1, 8-bit operands, through its first 5 of 27 layers: layer 0
+    dense, then 4 MoE layers (a whole period plus four), at published
+    widths (`repro.configs.deepseek_v2_lite`, `repro.serve.prefill`),
+    routing drawn from seed 0; embedding lookup and LM head left out."""
+    from repro.configs.deepseek_v2_lite import CONFIG
+    from repro.serve.prefill import mla_moe_prefill
+    return mla_moe_prefill(CONFIG, 4096, n_layers=5, seed=0)
+
+
 EXPLORATION_WORKLOADS = {
     "resnet18": resnet18,
     "mobilenetv2": mobilenetv2,

@@ -17,6 +17,14 @@ Per-CN attributes (paper Fig. 5):
     when it finishes (exact half-space intersection math, see
     `_exclusive_volume`),
   - `new_outputs`: final output elements first produced by this CN.
+
+Layers that say what they read (`Layer.mapped`: channel slices, matmul
+operand roles, a causal key axis, routed row maps) split along their token
+rows OY only and take their input regions from those fields
+(`_mapped_cns`): a causal CN producing rows [a, b) spans keys [0, b), so
+the CNs of a causal layer tile its causal extent exactly at band
+granularity; a routed layer's CN reads the producer rows of its own routed
+tokens. Every other layer is split as above, unchanged.
 """
 from __future__ import annotations
 
@@ -24,7 +32,10 @@ import dataclasses
 import math
 from typing import Mapping, Sequence
 
-from repro.core.workload import FULL_FANIN_OPS, Layer, Workload
+import numpy as np
+
+from repro.core.workload import (ACT_OPERAND_OPS, FULL_FANIN_OPS, Layer,
+                                 Workload)
 
 # Dims along which CNs may be split (spatial output dims, non-reduction).
 SPLITTABLE = ("OY", "OX")
@@ -71,6 +82,9 @@ class CN:
     weight_bytes: int            # layer weights (shared across the layer's CNs)
     in_bits: int = 8
     out_bits: int = 8
+    # per-CN reduction extents that replace the layer's (a causal layer
+    # whose keys lie on C reduces over its band's key prefix only)
+    reduce: tuple[tuple[str, int], ...] = ()
 
     @property
     def out_bytes(self) -> int:
@@ -86,8 +100,11 @@ class CN:
         """
         sig = getattr(self, "_sig", None)
         if sig is None:
-            sig = self._sig = (self.layer, tuple(sorted(
+            sig = (self.layer, tuple(sorted(
                 (d, b - a) for d, a, b in self.out_rect.ranges)))
+            if self.reduce:
+                sig += (self.reduce,)
+            self._sig = sig
         return sig
 
 
@@ -158,6 +175,15 @@ def identify_cns(
     for lid in workload.topo_order():
         layer = workload.layers[lid]
         splits = resolve_splits(layer, granularity, min_tile)
+        if layer.mapped:
+            cns.extend(_mapped_cns(workload, layer, splits, len(cns)))
+            continue
+        for p in layer.inputs:
+            prod = workload.layers[p]
+            if prod.rows is not None or prod.d("B") != layer.d("B"):
+                raise ValueError(
+                    f"layer {layer.name} reads {prod.name}, whose rows are "
+                    f"routed or whose batch axis differs: give it `reads`")
         dims = [d for d in SPLITTABLE if d in splits]
         _, _, iy_ext, ix_ext = layer.in_shape
         total_out = layer.out_elems
@@ -259,6 +285,131 @@ def identify_cns(
                 discardable_inputs=discardable, new_inputs=fresh, new_outputs=new_out,
                 weight_bytes=wb, in_bits=bits, out_bits=bits,
             ))
+    return cns
+
+
+def default_channels(layer: Layer, producer: Layer) -> tuple[int, int]:
+    """Channel slice of `producer` that `layer` reads when `reads` does not
+    say: C channels for conv/fc, all of a matmul operand, K otherwise."""
+    if layer.op in ("conv", "fc"):
+        return (0, layer.d("C"))
+    if layer.op in ACT_OPERAND_OPS:
+        return (0, producer.d("K"))
+    return (0, layer.d("K"))
+
+
+def input_ports(workload: Workload, layer: Layer) -> list[tuple]:
+    """Per input of a mapped layer: (producer id, channel lo, channel hi,
+    role, whether the channels lie on the causal key axis). The slice is
+    clipped to the producer's channels."""
+    n = len(layer.inputs)
+    reads = layer.reads or (None,) * n
+    roles = layer.roles or ("a",) * n
+    key_in_channels = {"K": layer.op not in ACT_OPERAND_OPS,
+                       "C": layer.op in ACT_OPERAND_OPS}.get(layer.causal,
+                                                             False)
+    ports = []
+    for p, rd, role in zip(layer.inputs, reads, roles):
+        prod = workload.layers[p]
+        lo, hi = rd if rd is not None else default_channels(layer, prod)
+        ports.append((p, max(lo, 0), min(hi, prod.d("K")), role,
+                      role == "a" and key_in_channels))
+    return ports
+
+
+def input_rows(workload: Workload, layer: Layer, p: int, role: str,
+               a: int, b: int) -> tuple[int, int, int]:
+    """Rows of producer `p` that the CN of `layer` producing rows [a, b)
+    reads, as (first, stop, count) in the producer's OY index space.
+
+    Operand B reads the causal prefix [0, b), or all rows; other inputs
+    read their band. A routed layer reading token-space rows reads its
+    own tokens (count rows between first and stop, which are not all
+    read); a token-space layer reading a routed producer reads the
+    producer rows routed from its band, a contiguous index range."""
+    prod = workload.layers[p]
+    if role == "b":
+        stop = b if layer.causal is not None else prod.d("OY")
+        if layer.rows is not None or prod.rows is not None:
+            raise ValueError(f"{layer.name}: operand B rows are not routed")
+        return 0, stop, stop
+    if layer.rows == prod.rows:
+        return a, b, b - a
+    if prod.rows is None:
+        tokens = layer.rows[a:b]
+        return tokens[0], tokens[-1] + 1, b - a
+    if layer.rows is None:
+        lo, hi = np.searchsorted(prod.rows, (a, b)).tolist()
+        return lo, hi, hi - lo
+    raise ValueError(f"{layer.name} and {prod.name} route different rows")
+
+
+def _mapped_cns(workload: Workload, layer: Layer, splits: dict[str, int],
+                first_id: int) -> list[CN]:
+    """CNs of a layer that says what it reads: one per band of its token
+    rows OY, each reading its inputs' ports (see `input_ports`,
+    `input_rows`). Exclusive and fresh input volumes compare each band's
+    reads with the next and the previous band's."""
+    if ("OX" in splits or layer.stride != 1 or layer.padding
+            or layer.d("FY") != 1 or layer.d("FX") != 1):
+        raise ValueError(f"{layer.name}: a mapped layer is pointwise along "
+                         f"its token rows and split along them only")
+    if not layer.inputs or len(set(layer.inputs)) != len(layer.inputs):
+        raise ValueError(f"{layer.name}: a mapped layer reads other layers, "
+                         f"each once")
+    oy = layer.d("OY")
+    key = layer.causal
+    if key is not None and layer.d(key) != oy:
+        raise ValueError(f"{layer.name}: causal key axis {key} must have "
+                         f"the extent of OY")
+    bands = _split_ranges(oy, splits.get("OY", 1))
+    ports = input_ports(workload, layer)
+    b_ext, k_ext, c_ext, ox = (layer.d("B"), layer.d("K"), layer.d("C"),
+                               layer.d("OX"))
+    elementwise = layer.op in ("add", "concat")
+
+    reads = []      # per band: [(rect, elements read, routed?)] per port
+    for a, b in bands:
+        band = []
+        for p, lo, hi, role, on_key in ports:
+            prod = workload.layers[p]
+            r0, r1, count = input_rows(workload, layer, p, role, a, b)
+            c1 = min(hi, lo + b) if on_key else hi
+            rect = Rect((("B", 0, prod.d("B")), ("K", lo, c1),
+                         ("OY", r0, r1), ("OX", 0, prod.d("OX"))))
+            vol = prod.d("B") * max(c1 - lo, 0) * count * prod.d("OX")
+            band.append((rect, vol, count != r1 - r0))
+        reads.append(band)
+
+    def shared(t: int, u: int) -> int:
+        """Input elements that bands t and u both read."""
+        if not 0 <= u < len(bands):
+            return 0
+        return sum(0 if sparse else rt.intersection_volume(ru)
+                   for (rt, _, sparse), (ru, _, _) in zip(reads[t], reads[u]))
+
+    cns = []
+    for t, (a, b) in enumerate(bands):
+        k_cn = b if key == "K" else k_ext
+        c_cn = b if key == "C" else c_ext
+        out_rect = Rect((("B", 0, b_ext), ("K", 0, k_cn), ("OY", a, b),
+                         ("OX", 0, ox)))
+        if elementwise:
+            macs = b_ext * k_cn * (b - a) * ox
+        else:
+            macs = b_ext * k_cn * c_cn * (b - a) * ox
+        vol = sum(v for _, v, _ in reads[t])
+        cns.append(CN(
+            id=first_id + t, layer=layer.id,
+            idx=(t,) if len(bands) > 1 else (), intra_rank=t,
+            out_rect=out_rect,
+            in_rects={p: rect for (p, *_), (rect, _, _)
+                      in zip(ports, reads[t])},
+            macs=macs, discardable_inputs=vol - shared(t, t + 1),
+            new_inputs=vol - shared(t, t - 1),
+            new_outputs=out_rect.volume(), weight_bytes=layer.weight_bytes,
+            in_bits=layer.bits, out_bits=layer.bits,
+            reduce=(("C", c_cn),) if key == "C" else ()))
     return cns
 
 

@@ -67,6 +67,7 @@ class CostModel:
             dims[d] = layer.d(d)
         if layer.op in ("dwconv", "pool", "add", "concat"):
             dims["C"] = 1
+        dims.update(cn.reduce)
         return dims
 
     def cost(self, cn: CN, core_id: int) -> CNCost | None:

@@ -6,6 +6,15 @@ per producer/consumer layer pair by building an R-tree over the consumer CNs'
 required-input boxes and bulk-querying it with all producer CNs' produced-
 output boxes at once (paper Fig. 6); edge weight = intersection volume in
 bytes, computed vectorized over the surviving (producer, consumer) pairs.
+A routed layer's CNs read scattered token rows, which no box describes:
+their edges from a token-space producer count, per pair, the routed tokens
+that fall in the producer's band (`_dispatch_edges`).
+
+On graphs with such layers (`Layer.mapped`), consumers of one producer CN
+read different parts of it: channel slices, causal prefixes, routed rows.
+Each data edge then also carries its *footprint* (`_footprints`): which
+(channel, row) elements of the producer's output it reads, as a bit mask,
+so the schedulers ship to each core only what has not reached it yet.
 
 The graph is stored array-native: CSR adjacency (``indptr``/``indices``/
 ``edge bytes`` for both directions) plus dense per-CN attribute arrays, so the
@@ -57,10 +66,13 @@ class CNGraph:
     """
 
     def __init__(self, cns: list[CN], edge_u: np.ndarray, edge_v: np.ndarray,
-                 edge_b: np.ndarray):
+                 edge_b: np.ndarray, footprints: dict | None = None):
         self.cns = cns
         n = len(cns)
         self.n = n
+        # data edge u -> v (key u * n + v) -> (mask, parts, bits), see
+        # `_footprints`; None on graphs whose layers read whole tensors
+        self.footprints = footprints
         edge_u = np.asarray(edge_u, dtype=np.int64)
         edge_v = np.asarray(edge_v, dtype=np.int64)
         edge_b = np.asarray(edge_b, dtype=np.int64)
@@ -118,6 +130,45 @@ class CNGraph:
         return zero, data
 
     @functools.cached_property
+    def footprint_pieces(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The footprints cut into pieces, for the batched fitness: per
+        producer CN, its output elements grouped by the set of successor
+        slots (positions in `succ_indices`, in order) whose edges read them.
+        Returns (producer (Np,), reader slots (Np, R) in increasing order,
+        padded with -1, bytes (Np,))."""
+        n, fps = self.n, self.footprints
+        ptr, idx = self.succ_indptr.tolist(), self.succ_indices.tolist()
+        prod, readers, nbytes = [], [], []
+        for u in range(n):
+            reads = [(s, fps[u * n + v])
+                     for s, v in enumerate(idx[ptr[u]:ptr[u + 1]])
+                     if u * n + v in fps]
+            if not reads:
+                continue
+            width = max(fp[0].bit_length() for _, fp in reads)
+            grid = np.zeros((len(reads), width), dtype=bool)
+            elems = np.zeros(width)
+            for r, (_, (mask, parts, _)) in enumerate(reads):
+                grid[r] = _bits(mask, width)
+                for m, e in parts:
+                    elems[_bits(m, width)] = e
+            cells, inv = np.unique(grid.T, axis=0, return_inverse=True)
+            cell_elems = np.bincount(inv.ravel(), weights=elems,
+                                     minlength=len(cells))
+            bits = reads[0][1][2]
+            for cell, e in zip(cells, cell_elems):
+                if cell.any():
+                    prod.append(u)
+                    readers.append([reads[r][0] for r in np.flatnonzero(cell)])
+                    nbytes.append(e * bits / 8.0)
+        width = max((len(r) for r in readers), default=1)
+        slots = np.full((len(readers), width), -1, dtype=np.int32)
+        for k, r in enumerate(readers):
+            slots[k, :len(r)] = r
+        return (np.asarray(prod, dtype=np.int32), slots,
+                np.asarray(nbytes, dtype=np.float64))
+
+    @functools.cached_property
     def succ_tuples(self) -> list[tuple[int, ...]]:
         ptr = self.succ_indptr.tolist()
         idx = self.succ_indices.tolist()
@@ -165,6 +216,123 @@ class CNGraph:
         return np.diff(self.pred_indptr)
 
 
+def _dispatch_edges(workload: Workload, layer, prod_lid: int,
+                    cons_cns: Sequence[CN], prod_cns: Sequence[CN]):
+    """Edges from a token-space producer to a routed layer: per (producer
+    CN, consumer CN) pair, the consumer's routed tokens inside the
+    producer's rows, times the channels and batch the consumer reads of
+    it, in bytes; producer-major order, as the R-tree path gives."""
+    prod = workload.layers[prod_lid]
+    rows = np.asarray(layer.rows, dtype=np.int64)
+    rect = cons_cns[0].in_rects[prod_lid].as_dict()
+    per_row = (rect["B"][1] - rect["B"][0]) * (rect["K"][1] - rect["K"][0]) \
+        * (rect["OX"][1] - rect["OX"][0])
+    c_a = np.array([c.out_rect.as_dict()["OY"][0] for c in cons_cns])
+    c_b = np.array([c.out_rect.as_dict()["OY"][1] for c in cons_cns])
+    p_rows = np.array([p.out_rect.as_dict()["OY"] for p in prod_cns])
+    # routed rows of consumer j below token t: searchsorted, then per pair
+    # the count in [p_lo, p_hi) clipped to the consumer's own rows [a, b)
+    lo = np.searchsorted(rows, p_rows[:, 0])[:, None]
+    hi = np.searchsorted(rows, p_rows[:, 1])[:, None]
+    count = np.clip(np.minimum(hi, c_b[None]) - np.maximum(lo, c_a[None]),
+                    0, None)                              # (n_prod, n_cons)
+    pi, ci = np.nonzero(count)
+    ids_p = np.array([p.id for p in prod_cns], dtype=np.int64)
+    ids_c = np.array([c.id for c in cons_cns], dtype=np.int64)
+    return (ids_p[pi], ids_c[ci],
+            count[pi, ci] * per_row * prod.bits // 8)
+
+
+def _bits(mask: int, width: int) -> np.ndarray:
+    """The low `width` bits of `mask` as a bool array, bit 0 first."""
+    raw = np.frombuffer(mask.to_bytes((width + 7) // 8, "little"), np.uint8)
+    return np.unpackbits(raw, bitorder="little")[:width].astype(bool)
+
+
+def _row_mask(rows: np.ndarray) -> int:
+    """Bit mask with the bits of `rows` (non-negative ints) set."""
+    if not rows.size:
+        return 0
+    flags = np.zeros(int(rows.max()) + 1, dtype=bool)
+    flags[rows] = True
+    return int.from_bytes(np.packbits(flags, bitorder="little").tobytes(),
+                          "little")
+
+
+def _footprints(workload: Workload, cns: Sequence[CN], eu: np.ndarray,
+                ev: np.ndarray, eb: np.ndarray) -> dict[int, tuple]:
+    """What each data edge u -> v reads of producer CN u's output.
+
+    The output of u is a grid of channel atoms by rows: its channels cut
+    wherever an out-edge's channel slice starts or ends, its rows those of
+    its band. An edge reads a channel slice of a set of rows (a band, a
+    causal prefix, or the routed tokens of a dispatch edge); bit
+    `a * rows + r` marks atom a of row r. Returns, keyed u * n + v,
+    (mask of the bits read, ((bits of one atom, elements per bit), ...),
+    the producer's bits per element). Edges must read the producer's whole
+    batch B and width OX, and their bytes must equal the footprint's."""
+    n = len(cns)
+    outs: dict[int, list[tuple[int, int]]] = {}
+    for u, v, b in zip(eu.tolist(), ev.tolist(), eb.tolist()):
+        if b:
+            outs.setdefault(u, []).append((v, b))
+    far = (-(1 << 60), 1 << 60)
+    fps: dict[int, tuple] = {}
+    for u, edges in outs.items():
+        cu = cns[u]
+        prod = workload.layers[cu.layer]
+        out = cu.out_rect.as_dict()
+        k0, k1 = out.get("K", (0, prod.d("K")))
+        pa, pb = out["OY"]
+        plane = 1
+        for d in ("B", "OX"):
+            a, b = out.get(d, (0, prod.d(d)))
+            plane *= b - a
+        reads = []
+        for v, nbytes in edges:
+            cv = cns[v]
+            cons = workload.layers[cv.layer]
+            need = cv.in_rects[cu.layer].as_dict()
+            for d in ("B", "OX"):
+                a, b = out.get(d, (0, prod.d(d)))
+                lo, hi = need.get(d, far)
+                if lo > a or hi < b:
+                    raise ValueError(
+                        f"{cons.name} reads part of {prod.name}'s {d} axis: "
+                        f"footprints cover channels and rows only")
+            lo, hi = need.get("K", far)
+            lo, hi = max(lo, k0), min(hi, k1)
+            if cons.rows is not None and prod.rows is None:
+                a, b = cv.out_rect.as_dict()["OY"]
+                toks = np.asarray(cons.rows[a:b], dtype=np.int64)
+                rows = _row_mask(toks[(toks >= pa) & (toks < pb)] - pa)
+            else:
+                r0, r1 = need.get("OY", far)
+                r0, r1 = max(r0, pa), min(r1, pb)
+                rows = ((1 << (r1 - r0)) - 1) << (r0 - pa) if r1 > r0 else 0
+            reads.append((v, nbytes, lo, hi, rows))
+        cuts = sorted({k0, k1, *(min(max(c, k0), k1) for _, _, lo, hi, _
+                                 in reads for c in (lo, hi))})
+        n_rows = pb - pa
+        for v, nbytes, lo, hi, rows in reads:
+            parts = tuple(
+                (rows << (a * n_rows), plane * (c1 - c0))
+                for a, (c0, c1) in enumerate(zip(cuts, cuts[1:]))
+                if lo <= c0 and c1 <= hi)
+            mask = 0
+            elems = 0
+            for m, e in parts:
+                mask |= m
+                elems += m.bit_count() * e
+            if elems * prod.bits // 8 != nbytes:
+                raise ValueError(
+                    f"edge {u} -> {v} ({prod.name} -> "
+                    f"{workload.layers[cns[v].layer].name}): {nbytes} bytes, "
+                    f"footprint of {elems} elements")
+            fps[u * n + v] = (mask, parts, prod.bits)
+    return fps
+
+
 def build_cn_graph(
     workload: Workload,
     cns: Sequence[CN],
@@ -193,6 +361,14 @@ def build_cn_graph(
         for prod_lid in cons_layer.inputs:
             prod_cns = by_layer[prod_lid]
             prod_ids = np.array([p.id for p in prod_cns], dtype=np.int64)
+            if (cons_layer.rows is not None
+                    and workload.layers[prod_lid].rows is None):
+                u, v, b = _dispatch_edges(workload, cons_layer, prod_lid,
+                                          cons_cns, prod_cns)
+                chunks_u.append(u)
+                chunks_v.append(v)
+                chunks_b.append(b)
+                continue
             cons_boxes = _rects_to_boxes([c.in_rects[prod_lid] for c in cons_cns])
             prod_boxes = boxes_of.get(prod_lid)
             if prod_boxes is None:
@@ -238,4 +414,7 @@ def build_cn_graph(
     else:
         eu = ev = eb = np.empty(0, dtype=np.int64)
 
-    return CNGraph(list(cns), eu, ev, eb)
+    footprints = None
+    if any(layer.mapped for layer in workload.layers.values()):
+        footprints = _footprints(workload, cns, eu, ev, eb)
+    return CNGraph(list(cns), eu, ev, eb, footprints)

@@ -15,6 +15,15 @@ Event-list scheduler over the fine-grained CN graph. Resources:
     input activations, and activation spills when a core's activation memory
     overflows, all FCFS on the port.
 
+What a communication node ships (the shipping rule): on a graph whose
+edges carry footprints (`CNGraph.footprints`: LLM graphs, whose consumers
+read channel slices, causal prefixes and routed rows of one producer CN),
+an edge ships the elements of its footprint that the consumer's core does
+not hold yet, and a consumer whose footprint is all on its core waits for
+that core's last arrival from the producer. On other graphs (the paper's
+CNNs) a producer CN's output ships to a core once, sized by the first
+consumer there: min(edge bytes, bytes of it not yet shipped to any core).
+
 Two candidate-selection priorities (paper Fig. 8):
   * 'latency': pick the candidate whose predecessors finished earliest
     (its data has waited in memory the longest) -> maximizes core utilization;
@@ -55,6 +64,7 @@ import numpy as np
 
 from repro.core.costmodel import CostModel
 from repro.core.depgraph import CNGraph
+from repro.core.workload import ACT_OPERAND_OPS
 from repro.hw.accelerator import Accelerator
 
 PREFETCH_DEPTH = 4.0  # external-input staging depth (quad-buffered prefetch)
@@ -109,6 +119,16 @@ class ScheduleResult:
 
     def utilization(self) -> np.ndarray:
         return self.core_busy / max(self.latency_cc, 1.0)
+
+
+def total_energy(energy: dict[str, float]) -> float:
+    """Total of the four energy terms, added left to right in the order
+    compute, sram, bus, dram: both schedulers' one formula. (`sum()`
+    would not do: since Python 3.12 it adds floats with compensation, and
+    drops it at the first term that is not exactly a `float`, so its last
+    bit depends on the terms' types.)"""
+    return float(energy["compute"] + energy["sram"] + energy["bus"]
+                 + energy["dram"])
 
 
 def compute_segments(workload, allocation, accelerator) -> np.ndarray:
@@ -195,6 +215,7 @@ class ScheduleEngine:
         hot = graph.hot_lists
         self._pred_pairs = graph.pred_pairs
         self._pred_zero, self._pred_data = graph.pred_split
+        self._footprints = graph.footprints
         self._succ_of = graph.succ_tuples
         self._indeg0 = hot["indeg"]
         self._zeros_n = [0] * self.n
@@ -259,6 +280,22 @@ class ScheduleEngine:
             np.searchsorted(graph.layer, np.arange(self.n_layers)).tolist()
             if self._ckpt_ok else None)
         self._strict_starts = list(range(self.n_layers))
+        # dependency edges resolved per schedule, for the tracer: prefix
+        # sums of each CN's in-edges, all and those of the activation-
+        # operand and routed layers (`ACT_OPERAND_OPS`, `Layer.rows`), so a
+        # schedule resumed at CN k0 counts the edges of CNs k0.. at once
+        n_in = np.diff(graph.pred_indptr)
+        layers = list(wl.layers.values())
+        routed = np.array([l.rows is not None for l in layers], dtype=bool)
+        operand_layer = routed | np.array(
+            [l.op in ACT_OPERAND_OPS for l in layers], dtype=bool)
+        cons = np.repeat(graph.layer, n_in)
+        prod = graph.layer[graph.pred_indices]
+        operand = (prod != cons) & (operand_layer[cons] | routed[prod])
+        self._edges_cum = np.concatenate([[0], np.cumsum(n_in)]).tolist()
+        self._operand_cum = np.concatenate([[0], np.cumsum(np.bincount(
+            np.repeat(np.arange(self.n), n_in)[operand],
+            minlength=self.n))]).tolist()
         self.checkpointing = True          # default for record=False schedules
         self.ckpt_capacity = 512           # snapshots kept per engine (LRU)
         # snapshot spacing: skip barriers closer than this many CNs to the
@@ -411,6 +448,7 @@ class ScheduleEngine:
         external_of = self._external_of
         w_cap, is_aimc, shared_l1 = self._w_cap, self._is_aimc, self._shared_l1
         routes, chan_bw, chan_e = self._routes, self._chan_bw, self._chan_e
+        footprints = self._footprints
         heappush, heappop = heapq.heappush, heapq.heappop
         heap_code = self._heap_code
         code_mask = self._code_mask
@@ -445,12 +483,12 @@ class ScheduleEngine:
             act_used = [0.0] * n_cores
             resident: list[OrderedDict[int, int]] = [OrderedDict() for _ in range(n_cores)]
             resident_used = [0.0] * n_cores
-            # fresh-byte bookkeeping: a producer CN's output is shipped to a
-            # given core at most once (consumers on that core share the
-            # data); keys are packed cn * n_cores + core — int-keyed dicts
-            # hash faster and are invisible to the cyclic GC once snapshotted
+            # fresh-byte bookkeeping (the module's shipping rule): keys are
+            # packed cn * n_cores + core — int-keyed dicts hash faster and
+            # are invisible to the cyclic GC once snapshotted
             sent_to: dict[int, float] = {}       # cn/core -> arrival time
             remaining_new: dict[int, int] = {}   # cn -> bytes left to ship
+            held: dict[int, int] = {}            # cn/core -> footprint bits
             spilled: dict[int, float] = {}       # cn -> bytes pushed to DRAM
             have_spills = False
             e_compute = e_sram = e_bus = e_dram = 0.0
@@ -471,7 +509,7 @@ class ScheduleEngine:
             (k0, fin_p, indeg_s, rk_s, s_core_free, s_core_busy, s_act_used,
              s_res_used, s_resident, s_sent, s_rem, s_spill, have_spills,
              bus_free, dram_free, frontier, e_compute, e_sram, e_bus, e_dram,
-             comm_max, dram_max, s_barrier, ready_ids, s_chan) = snap
+             comm_max, dram_max, s_barrier, ready_ids, s_chan, s_held) = snap
             chan_free = list(s_chan)
             self.ckpt_stats["resume_hits"] += 1
             self.ckpt_stats["cns_skipped"] += k0
@@ -482,6 +520,7 @@ class ScheduleEngine:
             resident = [OrderedDict(r) for r in s_resident]
             sent_to = dict(s_sent)
             remaining_new = dict(s_rem)
+            held = dict(s_held)
             spilled = dict(s_spill)
             finish = list(fin_p) + [0.0] * (n - k0)
             indeg = [0] * k0 + list(indeg_s)
@@ -566,7 +605,7 @@ class ScheduleEngine:
                                 dict(spilled), have_spills, bus_free,
                                 dram_free, frontier, e_compute, e_sram, e_bus,
                                 e_dram, comm_max, dram_max, dict(seg_barrier),
-                                tuple(ready), tuple(chan_free))
+                                tuple(ready), tuple(chan_free), dict(held))
                             self.ckpt_stats["snapshots"] += 1
                             if len(store) > self.ckpt_capacity:
                                 store.popitem(last=False)
@@ -596,17 +635,41 @@ class ScheduleEngine:
                     if fu > data_ready:
                         data_ready = fu
                 else:
+                    # what crosses (the module's shipping rule): without
+                    # footprints the first consumer on a core ships
+                    # min(edge, bytes of u not yet shipped anywhere) and the
+                    # others on that core wait for it; with footprints an
+                    # edge ships the elements it reads that its core does
+                    # not hold yet, and waits for the last arrival if none
                     skey = u * n_cores + core
-                    arrived = sent_to.get(skey)
+                    if footprints is None:
+                        arrived = sent_to.get(skey)
+                        if arrived is None:
+                            rem = remaining_new.get(u)
+                            if rem is None:
+                                rem = out_bytes[u]
+                            fresh = e_bytes if e_bytes < rem else rem
+                            remaining_new[u] = rem - fresh
+                    else:
+                        mask, parts, fbits = footprints[u * n + i]
+                        have = held.get(skey)
+                        if have is None:         # the core holds none of u
+                            held[skey] = mask
+                            fresh = e_bytes
+                            arrived = None
+                        elif mask | have == have:    # it holds all it reads
+                            arrived = sent_to[skey]
+                        else:
+                            held[skey] = have | mask
+                            elems = 0
+                            for m, e in parts:
+                                elems += (m & ~have).bit_count() * e
+                            fresh = elems * fbits // 8
+                            arrived = None
                     if arrived is not None:
                         if arrived > data_ready:
                             data_ready = arrived
                     else:
-                        rem = remaining_new.get(u)
-                        if rem is None:
-                            rem = out_bytes[u]
-                        fresh = e_bytes if e_bytes < rem else rem
-                        remaining_new[u] = rem - fresh
                         fu = finish[u]
                         if routes is None:
                             start = bus_free if bus_free > fu else fu
@@ -802,7 +865,7 @@ class ScheduleEngine:
 
         latency = max(frontier if n else 0.0, comm_max, dram_max)
         energy = {"compute": e_compute, "sram": e_sram, "bus": e_bus, "dram": e_dram}
-        total_e = e_compute + e_sram + e_bus + e_dram
+        total_e = total_energy(energy)
 
         # ---- Step 5.2: activation memory usage trace (vectorized) ----------
         if record:
@@ -830,6 +893,10 @@ class ScheduleEngine:
             tracer.count("engine.schedules")
             tracer.count("engine.cns_walked", n - n_resumed)
             tracer.count("engine.cns_resumed", n_resumed)
+            tracer.count("engine.edges_walked",
+                         self._edges_cum[n] - self._edges_cum[n_resumed])
+            tracer.count("engine.operand_edges",
+                         self._operand_cum[n] - self._operand_cum[n_resumed])
         if validate:
             if not record:
                 raise ValueError("validate=True needs record=True "
@@ -960,10 +1027,10 @@ def schedule_reference(
     resident: list[OrderedDict[int, int]] = [OrderedDict() for _ in accelerator.cores]
     resident_used = np.zeros(accelerator.n_cores)
 
-    # fresh-byte bookkeeping: a producer CN's output is shipped to a given core
-    # at most once (consumers on that core share the landed data)
+    # fresh-byte bookkeeping (the module's shipping rule)
     sent_to: dict[tuple[int, int], float] = {}  # (cn, core) -> arrival time
     remaining_new: dict[int, int] = {}          # cn -> bytes left to ship
+    held: dict[tuple[int, int], int] = {}       # (cn, core) -> footprint bits
     spilled: dict[int, float] = {}              # cn -> bytes pushed to DRAM
 
     energy = {"compute": 0.0, "sram": 0.0, "bus": 0.0, "dram": 0.0}
@@ -1055,14 +1122,25 @@ def schedule_reference(
                 data_ready = max(data_ready, finish[u])
             else:
                 key = (u, core)
-                if key in sent_to:
+                if graph.footprints is None:
+                    ship = key not in sent_to
+                    if ship:
+                        rem = remaining_new.get(u)
+                        if rem is None:
+                            rem = cns[u].out_bytes
+                        fresh = min(e_bytes, rem)
+                        remaining_new[u] = rem - fresh
+                else:
+                    mask, parts, fbits = graph.footprints[u * n + i]
+                    have = held.get(key, 0)
+                    ship = mask & ~have != 0
+                    if ship:
+                        held[key] = have | mask
+                        fresh = sum((m & ~have).bit_count() * e
+                                    for m, e in parts) * fbits // 8
+                if not ship:
                     data_ready = max(data_ready, sent_to[key])
                 else:
-                    rem = remaining_new.get(u)
-                    if rem is None:
-                        rem = cns[u].out_bytes
-                    fresh = min(e_bytes, rem)
-                    remaining_new[u] = rem - fresh
                     if topo_routes is None:
                         start = max(bus_free, finish[u])
                         dur = fresh * 8.0 / bus_bw
@@ -1159,7 +1237,7 @@ def schedule_reference(
         max((e for _, e, *_ in comm_intervals), default=0.0),
         max((e for _, e, *_ in dram_intervals), default=0.0),
     ))
-    total_e = float(sum(energy.values()))
+    total_e = total_energy(energy)
 
     # ---- Step 5.2: activation memory usage trace ----------------------------
     from repro.core.memtrace import peak_memory

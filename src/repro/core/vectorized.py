@@ -202,6 +202,14 @@ class BatchedFitness:
             for d in range(ptr[v + 1] - ptr[v]):
                 edge_slot[v, d] = slot_of[(int(idx[ptr[v] + d]), v)]
         self.smax = max(smax, 1)
+        # footprint pieces (LLM graphs, see `CNGraph.footprint_pieces`):
+        # flat successor slot u * smax + s of each piece's readers, -1 pad
+        pieces = None
+        if graph.footprints is not None:
+            p_u, p_slots, p_bytes = graph.footprint_pieces
+            flat = np.where(p_slots >= 0, p_u[:, None] * self.smax + p_slots,
+                            -1).astype(np.int32)
+            pieces = (flat, p_bytes.astype(np.float32))
 
         tab = eng.tables
         feas = tab.feasible.astype(bool)
@@ -318,6 +326,9 @@ class BatchedFitness:
                 np.asarray(eng._layer_wb, dtype=np.float32)),
             "w_cap": jnp.asarray(np.asarray(eng._w_cap, dtype=np.float32)),
         }
+        if pieces is not None:
+            self._j["piece_slot"] = jnp.asarray(pieces[0])
+            self._j["piece_b"] = jnp.asarray(pieces[1])
         # (n+1, L) one-hot of each CN's wavefront level (pad row all-zero):
         # projects per-CN byte columns onto per-level sums with one matmul
         lvl_oh = np.zeros((n + 1, n_levels), dtype=np.float32)
@@ -412,13 +423,40 @@ class BatchedFitness:
             s0 = jnp.sum(cyc_ng) + jnp.sum(ecs_ng) + jnp.sum(seg_ng)
             return s0, s0
 
-        # fresh-byte dedup, exactly as the engine's `sent_to`/`remaining_new`
-        # bookkeeping but hoisted out of the time loop (it depends only on
-        # the allocation): a producer ships to a core once — the first
-        # crossing consumer on that core pays min(edge bytes, remaining
-        # budget), the budget starting at the producer's out_bytes
+        # fresh-byte dedup, the engine's shipping rule (`repro.core.
+        # scheduler`) hoisted out of the time loop (it depends only on the
+        # allocation), with consumers taken in successor-slot order
         fresh8_pred = None
-        if not self.shared_l1 and self.dmax:
+        if not self.shared_l1 and self.dmax and "piece_b" in j:
+            # footprints: a piece crosses to a core with its first reader
+            # there that sits on another core than the producer (`held`:
+            # which cores have it so far; core n_cores pads the readers)
+            scr = core_ng[j["succ_ids"]]                  # (n+1, S, P)
+            crossing = (j["succ_b"][:, :, None] > 0) & (
+                scr != core_ng[:, None])
+            flat_c = jnp.concatenate([
+                crossing.reshape(-1, p), jnp.zeros((1, p), bool)])
+            flat_core = jnp.concatenate([
+                scr.reshape(-1, p), jnp.full((1, p), n_cores, scr.dtype)])
+            held = jnp.zeros((j["piece_b"].shape[0], n_cores + 1, p), bool)
+            fresh = jnp.zeros(flat_c.shape, jnp.float32)
+            for k in range(j["piece_slot"].shape[1]):
+                slot = j["piece_slot"][:, k]              # (Np,), -1 = none
+                c_k = flat_core[slot]                     # (Np, P)
+                x_k = flat_c[slot]
+                on = jnp.take_along_axis(held, c_k[:, None], axis=1)[:, 0]
+                pays = x_k & ~on
+                held = held | (x_k[:, None]
+                               & (c_k[:, None] == jnp.arange(n_cores + 1)
+                                  [None, :, None]))
+                fresh = fresh.at[slot].add(
+                    jnp.where(pays, j["piece_b"][:, None], 0.0))
+            fresh8_pred = 8.0 * fresh[:-1].reshape(
+                n + 1, self.smax, p)[j["pred_ids"], j["edge_slot"]]
+        elif not self.shared_l1 and self.dmax:
+            # a producer ships to a core once — the first crossing consumer
+            # on that core pays min(edge bytes, remaining budget), the
+            # budget starting at the producer's out_bytes
             ucore = core_ng[:, None]                      # (n+1, 1, P)
             scr = core_ng[j["succ_ids"]]                  # (n+1, S, P)
             crossing = (j["succ_b"][:, :, None] > 0) & (scr != ucore)

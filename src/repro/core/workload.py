@@ -14,6 +14,31 @@ Supported op types:
   pool    : max/avg pool              (loops B K OY OX FY FX) - SIMD-mapped
   add     : elementwise residual add  (loops B K OY OX)       - SIMD-mapped
   concat  : channel concat (zero-cost data movement, scheduling-only node)
+  matmul  : activation x activation product (loops B K C OY OX), no weights:
+            both operands are tensors other layers produced. Inputs play
+            operand A (rows = the output rows OY, channels along C) or
+            operand B (rows along the `causal` key axis, or all rows).
+            Attention's scores (Q.K^T: B=heads, K=keys, C=head dim,
+            OY=queries) and context (P.V: B=heads, K=head dim, C=keys,
+            OY=queries) are this op.
+
+Three optional per-layer fields describe what a layer reads, for LLM graphs
+whose operands are slices, causal prefixes and routed subsets of other
+layers' outputs; a layer that sets none of them is read as before:
+
+  reads  : per input, the channel slice (lo, hi) of the producer's K axis it
+           reads (None = the op's default: C channels for conv/fc, K for
+           the elementwise ops, all of them for matmul).
+  roles  : per input of a matmul, "a" or "b" (which operand it feeds).
+  causal : the key axis ("K" or "C") of a causal layer: the CN producing
+           rows [a, b) spans keys [0, b) on that axis, and reads operand B
+           rows (or, where its input channels lie on the key axis, input
+           channels) [0, b). The key axis has the extent of OY.
+  rows   : a routed row map: the token position (in the producer's OY axis)
+           of each of this layer's OY rows, strictly increasing. An MoE
+           expert's layers carry their routed tokens; a layer without a
+           map that reads a routed one gathers, per token row, the rows
+           routed from it (the combine's scatter back).
 """
 from __future__ import annotations
 
@@ -30,6 +55,8 @@ SPATIAL_OPS = frozenset({"conv", "dwconv", "pool", "add", "concat"})
 FULL_FANIN_OPS = frozenset({"fc"})
 # Ops mapped to the SIMD core in the exploration study (pool / residual add).
 SIMD_OPS = frozenset({"pool", "add", "concat"})
+# Ops whose second operand is an activation, not a weight.
+ACT_OPERAND_OPS = frozenset({"matmul"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,9 +72,27 @@ class Layer:
     # ids of producer layers feeding each input operand (len 1, or 2 for add)
     inputs: Sequence[int] = ()
     bits: int = 8  # operand precision (paper targets 8b edge accelerators)
+    reads: Sequence | None = None           # per input: (lo, hi) or None
+    roles: Sequence[str] = ()               # per matmul input: "a" | "b"
+    causal: str | None = None               # key axis "K" | "C"
+    rows: Sequence[int] | None = None       # routed row map (token ids)
 
     def d(self, name: str) -> int:
         return int(self.dims.get(name, 1))
+
+    @property
+    def mapped(self) -> bool:
+        """Whether the layer sets any of `reads`, `roles`, `causal`, `rows`
+        (or is a matmul): its CNs and edges then follow those fields."""
+        return (self.op in ACT_OPERAND_OPS or self.reads is not None
+                or bool(self.roles) or self.causal is not None
+                or self.rows is not None)
+
+    def _causal_tri(self) -> int:
+        """Query-key pairs of the causal triangle: sum over query rows i of
+        the i + 1 keys it attends to."""
+        t = self.d("OY")
+        return t * (t + 1) // 2
 
     # ---- derived tensor geometry -------------------------------------------------
     @property
@@ -63,8 +108,13 @@ class Layer:
 
     @property
     def macs(self) -> int:
+        """Operations of the layer's equations; a causal layer counts each
+        query row against the keys up to and including its own."""
         if self.op in ("add", "concat"):
             return self.d("B") * self.d("K") * self.d("OY") * self.d("OX")
+        if self.causal is not None:
+            rest = (x for x in LOOP_DIMS if x not in (self.causal, "OY"))
+            return math.prod(self.d(x) for x in rest) * self._causal_tri()
         return math.prod(self.d(x) for x in LOOP_DIMS)
 
     @property
@@ -83,6 +133,8 @@ class Layer:
 
     @property
     def out_elems(self) -> int:
+        if self.causal == "K":
+            return self.d("B") * self._causal_tri() * self.d("OX")
         return math.prod(self.out_shape)
 
     @property
@@ -109,12 +161,34 @@ class Workload:
         padding: int = 0,
         inputs: Iterable[int] = (),
         bits: int = 8,
+        reads: Iterable | None = None,
+        roles: Iterable[str] = (),
+        causal: str | None = None,
+        rows: Iterable[int] | None = None,
     ) -> int:
         lid = len(self.layers)
         inputs = tuple(inputs)
+        if reads is not None:
+            reads = tuple(None if r is None else (int(r[0]), int(r[1]))
+                          for r in reads)
+            if len(reads) != len(inputs):
+                raise ValueError(f"{name}: one `reads` entry per input")
+        roles = tuple(roles)
+        if op in ACT_OPERAND_OPS and (len(roles) != len(inputs) or not
+                                      set(roles) <= {"a", "b"}):
+            raise ValueError(f"{name}: a matmul needs a role per input")
+        if causal not in (None, "K", "C"):
+            raise ValueError(f"{name}: causal key axis {causal!r}")
+        if rows is not None:
+            rows = tuple(int(r) for r in rows)
+            if len(rows) != int(dims.get("OY", 1)) or any(
+                    b <= a for a, b in zip(rows, rows[1:])):
+                raise ValueError(f"{name}: `rows` must give OY strictly "
+                                 f"increasing token ids")
         self.layers[lid] = Layer(
             id=lid, name=name, op=op, dims=dict(dims), stride=stride,
-            padding=padding, inputs=inputs, bits=bits,
+            padding=padding, inputs=inputs, bits=bits, reads=reads,
+            roles=roles, causal=causal, rows=rows,
         )
         self._succ[lid] = []
         for p in inputs:
@@ -147,7 +221,8 @@ class Workload:
         across repeated explorations of structurally identical workloads."""
         return (self.name, tuple(
             (l.id, l.op, tuple(sorted(l.dims.items())), l.stride, l.padding,
-             tuple(l.inputs), l.bits)
+             tuple(l.inputs), l.bits) + (
+                 (l.reads, l.roles, l.causal, l.rows) if l.mapped else ())
             for l in self.layers.values()))
 
     # ---- serialization (shard manifests ship workloads as pure data) ---------
@@ -157,7 +232,12 @@ class Workload:
         return {"name": self.name, "layers": [
             {"name": l.name, "op": l.op, "dims": dict(l.dims),
              "stride": l.stride, "padding": l.padding,
-             "inputs": list(l.inputs), "bits": l.bits}
+             "inputs": list(l.inputs), "bits": l.bits} | (
+                 {"reads": None if l.reads is None else [
+                     None if r is None else list(r) for r in l.reads],
+                  "roles": list(l.roles), "causal": l.causal,
+                  "rows": None if l.rows is None else list(l.rows)}
+                 if l.mapped else {})
             for l in self.layers.values()]}
 
     @classmethod
@@ -170,7 +250,9 @@ class Workload:
                                        for k, v in l["dims"].items()},
                   stride=int(l["stride"]), padding=int(l["padding"]),
                   inputs=tuple(int(i) for i in l["inputs"]),
-                  bits=int(l["bits"]))
+                  bits=int(l["bits"]), reads=l.get("reads"),
+                  roles=l.get("roles", ()), causal=l.get("causal"),
+                  rows=l.get("rows"))
         return w
 
     @property

@@ -13,6 +13,10 @@ implement the parts Stream consumes:
   so partial sums do not round-trip SRAM; per-level access counts follow,
 * the DATE'22 uniform latency model [29]: ideal cycles plus stall cycles when
   the per-cycle on-core SRAM traffic exceeds the SRAM port bandwidth.
+* an activation-operand matmul (`matmul`) maps like a conv whose "weights"
+  are operand B: another layer's output, held in the activation SRAM and
+  read at its energy, one matrix per batch index B (a head), so it is
+  reused across the output rows only and has no weight-memory residency.
 
 All constants are per-core calibratable; Table-I validation (benchmarks)
 fixes them against the three measured chips.
@@ -30,6 +34,7 @@ from repro.hw.core_model import CoreModel
 _INPUT_REUSE_DIMS = ("K",)              # one input broadcast to all K columns
 _WEIGHT_REUSE_DIMS = ("B", "OY", "OX")  # weights shared across output pixels
 _OUTPUT_REDUCE_DIMS = ("C", "FY", "FX")  # psums accumulate across these
+_OPERAND_B_REUSE_DIMS = ("OY", "OX")    # matmul operand B: per head
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,12 +98,16 @@ def cn_cost(dims: Mapping[str, int], op: str, core: CoreModel, bits: int = 8) ->
     # A) output-stationary: reduction loops innermost; psums stay in registers,
     #    but each MAC consumes a fresh weight (reused only across spatially-
     #    unrolled output dims).
-    spatial_out = math.prod(min(unroll.get(x, 1), d[x]) for x in _WEIGHT_REUSE_DIMS)
+    act_operand = op == "matmul"
+    spatial_out = math.prod(min(unroll.get(x, 1), d[x]) for x in (
+        _OPERAND_B_REUSE_DIMS if act_operand else _WEIGHT_REUSE_DIMS))
     w_reads_A = macs / max(spatial_out, 1)
     out_rw_A = out_elems
     # B) weight-stationary: output loops innermost; weights read once from
     #    SRAM, but psums round-trip SRAM once per residual reduction step.
     w_elems = d["K"] * d["C"] * d["FY"] * d["FX"]
+    if act_operand:
+        w_elems *= d["B"]
     t_red = math.prod(math.ceil(d[x] / unroll.get(x, 1)) for x in _OUTPUT_REDUCE_DIMS)
     w_reads_B = w_elems
     out_rw_B = out_elems * max(1, 2 * t_red - 1)
@@ -116,9 +125,14 @@ def cn_cost(dims: Mapping[str, int], op: str, core: CoreModel, bits: int = 8) ->
         candidates.append((cycles, sram_bits, in_bits, w_bits, out_bits_))
     cycles, sram_bits, in_bits, w_bits, out_bits_ = min(candidates)
 
-    w_energy = w_bits * core.weight_energy_pj_per_bit
     e_compute = macs * core.mac_energy_pj
-    e_act = (in_bits + out_bits_) * core.act_energy_pj_per_bit
+    if act_operand:
+        # operand B lives in the activation SRAM
+        w_energy = 0.0
+        e_act = (in_bits + w_bits + out_bits_) * core.act_energy_pj_per_bit
+    else:
+        w_energy = w_bits * core.weight_energy_pj_per_bit
+        e_act = (in_bits + out_bits_) * core.act_energy_pj_per_bit
     energy = e_compute + e_act + w_energy
     if core.core_type == "aimc":
         util = macs / max(temporal * core.n_pe, 1)  # per array activation

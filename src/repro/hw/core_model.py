@@ -67,6 +67,10 @@ class CoreModel:
         return cacti_like_energy_pj_per_bit(self.weight_mem_bytes)
 
     def supports(self, op: str) -> bool:
+        if op == "matmul":
+            # both operands stream from the activation SRAM: an AiMC array
+            # holds only stationary weights
+            return self.core_type == "digital"
         if self.core_type == "simd":
             return op in ("pool", "add", "concat")
         return op in ("conv", "dwconv", "fc", "pool", "add", "concat")

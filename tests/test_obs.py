@@ -336,6 +336,7 @@ def test_ga_generation_spans_and_store_hit_counter(tmp_path):
 
 # each span's possible parents (None: a root)
 _PARENTS = {"session.explore": {None},
+            "cn.graph": {"session.explore"},
             "ga.generation": {"session.explore"},
             "ga.exact": {"session.explore", "ga.generation"},
             "ga.variation": {"ga.generation"},
@@ -380,8 +381,10 @@ def test_wall_spans_nest_per_generation():
     assert ev[root].attrs == {"seed": 0}
     gens = [i for i in kids[root] if ev[i].name == "ga.generation"]
     assert len(gens) == len(res.ga.history)
+    # the session builds its CN graph inside the first exploration
     assert [ev[i].name for i in kids[root]] == (
-        ["ga.exact"] + ["ga.generation"] * len(gens) + ["engine.schedule"])
+        ["cn.graph", "ga.exact"] + ["ga.generation"] * len(gens)
+        + ["engine.schedule"])
     for g in gens:
         names = [ev[i].name for i in kids[g]]
         assert names[0] == "ga.variation" and names.count("ga.select") == 2
@@ -534,3 +537,67 @@ def test_trace_export_tool_is_deterministic(tmp_path):
         assert doc["traceEvents"]
     report = json.loads(blobs[0]["report_json"])
     assert report["slack_cc"] >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# CN graph span and the engine's edge counters
+# ---------------------------------------------------------------------------
+def _tiny_prefill():
+    import dataclasses
+    from repro.configs.deepseek_v2_lite import CONFIG
+    from repro.serve.prefill import mla_moe_prefill
+    cfg = dataclasses.replace(
+        CONFIG, d_model=64, n_heads=4, head_dim=16,
+        mla={"kv_lora": 16, "qk_nope": 16, "qk_rope": 8, "v_dim": 16},
+        moe={"n_routed": 8, "top_k": 2, "n_shared": 1, "d_ff_expert": 32,
+             "first_dense_layers": 1, "d_ff_dense": 128})
+    return mla_moe_prefill(cfg, 64, n_layers=2)
+
+
+def test_cn_graph_span_once_per_engine_build():
+    from repro.hw.catalog import mc_hetero
+    tr = Tracer()
+    sess = ExplorationSession(tracer=tr)
+    w, gran = _tiny_prefill(), ("tile", 4, 1)
+    sess.engine(w, mc_hetero(), gran)
+    sess.engine(w, mc_hetero(), gran)          # cached: no new graph
+    sess.engine(w, mc_hom_tpu(), gran)         # new engine, cached graph
+    sess.engine(fsrcnn(), mc_hom_tpu(), gran)  # a second graph
+    names = [e.name for e in tr.events]
+    assert names == ["cn.graph", "cn.graph"]
+    assert all(e.t1 > e.t0 and e.depth == 0 for e in tr.events)
+
+
+def test_edge_counters_equal_the_graphs_counts_for_a_cold_schedule():
+    from repro.core.workload import ACT_OPERAND_OPS
+    from repro.hw.catalog import mc_hetero
+    w, acc = _tiny_prefill(), mc_hetero()
+    graph = build_graph(w, acc, ("tile", 4, 1))
+    engine = ScheduleEngine(graph, CostModel(w, acc), acc)
+    tr = engine.tracer = Tracer()
+    engine.schedule(manual_pingpong(w, acc), record=False, checkpoint=False)
+    c = tr.snapshot()["counters"]
+    assert c["engine.edges_walked"] == graph.n_edges()
+    layers = w.layers
+    operand = sum(
+        1 for (u, v) in graph.edge_bytes
+        if graph.cns[u].layer != graph.cns[v].layer and (
+            layers[graph.cns[v].layer].op in ACT_OPERAND_OPS
+            or layers[graph.cns[v].layer].rows is not None
+            or layers[graph.cns[u].layer].rows is not None))
+    assert 0 < c["engine.operand_edges"] == operand < graph.n_edges()
+
+
+def test_edge_counters_absent_without_a_tracer():
+    w, acc, engine = _chip4_engine()
+    assert engine.tracer is None
+    engine.schedule(manual_pingpong(w, acc), record=False)
+    tr = engine.tracer = Tracer()
+    engine.schedule(manual_pingpong(w, acc), record=False, checkpoint=False)
+    c = tr.snapshot()["counters"]
+    # a CNN has edges but no activation-operand or routed layer
+    assert c["engine.edges_walked"] == engine.graph.n_edges() > 0
+    assert c["engine.operand_edges"] == 0
+    engine.tracer = None
+    res = engine.schedule(manual_pingpong(w, acc), record=False)
+    assert res.latency_cc > 0 and tr.snapshot()["counters"] == c

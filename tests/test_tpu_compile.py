@@ -86,6 +86,34 @@ def test_serialize_prefix_compiles_at_explorer_shapes(
         assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.fixture(scope="module")
+def llm_fitness():
+    """The explorer's device path on DeepSeek-V2-Lite's prefill ("tile",
+    8, 1) / mc_hetero: 4816 CNs, wavefronts up to 134 wide."""
+    from repro.api.session import ExplorationSession
+    from repro.configs.paper_workloads import deepseek_v2_lite_prefill
+    from repro.core.vectorized import BatchedFitness
+    from repro.hw.catalog import mc_hetero
+    engine = ExplorationSession().engine(deepseek_v2_lite_prefill(),
+                                         mc_hetero(), ("tile", 8, 1))
+    return BatchedFitness(engine, contention="serialize", use_pallas=True)
+
+
+def test_serialize_prefix_compiles_at_llm_width(one_chip, native_pallas,
+                                                llm_fitness):
+    """The kernel at the LLM cell's wavefront width, 134 lanes: not a
+    multiple of 128, which the lane rolls must still lower for."""
+    from repro.kernels.wavefront import serialize_prefix
+    bf = llm_fitness
+    assert bf.width == 134
+    for rows in (bf.n_cores, bf.n_chan):
+        free = _struct((32, rows), jnp.float32, one_chip)
+        items = _struct((32, rows, bf.width), jnp.float32, one_chip)
+        compiled = jax.jit(serialize_prefix).lower(free, items,
+                                                   items).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_batched_fitness_score_program_compiles(one_chip, native_pallas,
                                                 explorer_fitness):
     bf = explorer_fitness
