@@ -76,6 +76,31 @@ def _pow2_at_least(k: int) -> int:
     return 1 << max(k - 1, 1).bit_length() if k > 1 else 1
 
 
+def core_select(idx, table):
+    """`table[idx]` for a genome-dependent index `idx` into a core axis.
+
+    `table[c]` is core `c`'s value (the leading axis of an array, or a
+    list of per-core arrays) and broadcasts against `idx`. The index is
+    compared against each core and the match selected, so the result is
+    the table's own bits: no per-element gather, which the TPU serves
+    slowly, and no one-hot product, which `BIG`, `NEG` and `0 * BIG` would
+    change. An index past the last core takes the last entry. The chain
+    is as long as the axis, so it serves core axes (at most 17 in the
+    catalog), never an axis of layers or segments.
+
+        >>> import jax.numpy as jnp
+        >>> core_select(jnp.array([2, 0, 1]), jnp.array([5.0, 6.0, 7.0]))
+        Array([7., 5., 6.], dtype=float32)
+    """
+    import jax.numpy as jnp
+    last = len(table) - 1
+    out = jnp.broadcast_to(table[last], jnp.broadcast_shapes(
+        jnp.shape(idx), jnp.shape(table[last])))
+    for c in range(last - 1, -1, -1):
+        out = jnp.where(idx == c, table[c], out)
+    return out
+
+
 class BatchedFitness:
     """Vectorized approximate (latency, energy) for genome populations.
 
@@ -276,12 +301,10 @@ class BatchedFitness:
         if self.shared_l1:
             n_chan = 0
             route_inv = np.zeros((n_cores, n_cores, 1), dtype=np.float32)
-            route_tot = np.zeros((n_cores, n_cores), dtype=np.float32)
             route_e = np.zeros((n_cores, n_cores), dtype=np.float32)
         elif eng._routes is not None:
             n_chan = eng._n_chan
             route_inv = np.zeros((n_cores, n_cores, n_chan), dtype=np.float32)
-            route_tot = np.zeros((n_cores, n_cores), dtype=np.float32)
             route_e = np.zeros((n_cores, n_cores), dtype=np.float32)
             for u in range(n_cores):
                 for v in range(n_cores):
@@ -289,13 +312,11 @@ class BatchedFitness:
                         continue
                     for ch in eng._routes[u][v]:
                         route_inv[u, v, ch] += 1.0 / eng._chan_bw[ch]
-                        route_tot[u, v] += 1.0 / eng._chan_bw[ch]
                         route_e[u, v] += eng._chan_e[ch]
         else:
             n_chan = 1
             off = 1.0 - np.eye(n_cores, dtype=np.float32)
             route_inv = (off / float(acc.bus_bw_bits_per_cc))[:, :, None]
-            route_tot = off / float(acc.bus_bw_bits_per_cc)
             route_e = off * float(acc.bus_energy_pj_per_bit)
         self.n_chan = n_chan
 
@@ -311,8 +332,8 @@ class BatchedFitness:
             "edge_slot": jnp.asarray(edge_slot),
             "out_bytes": jnp.asarray(
                 np.concatenate([graph.out_bytes, [0]]).astype(np.float32)),
-            "cyc_nc": jnp.asarray(cyc_nc),
-            "ecs_nc": jnp.asarray(ecs_nc),
+            "cyc_cn": jnp.asarray(cyc_nc.T[:, :, None]),   # (C, n+1, 1)
+            "ecs_cn": jnp.asarray(ecs_nc.T[:, :, None]),
             "layer_pad": jnp.asarray(layer_pad),
             "dram_off": jnp.asarray(dram_off),
             "dram_tot": jnp.asarray(dram_tot),
@@ -334,6 +355,22 @@ class BatchedFitness:
         lvl_oh = np.zeros((n + 1, n_levels), dtype=np.float32)
         lvl_oh[np.arange(n), level] = 1.0
         self._j["lvl_oh"] = jnp.asarray(lvl_oh)
+
+        # elements one genome row looks up with `core_select` in one call
+        # of the program: the `fitness.core_selects` counter's unit
+        per_row = 2 * (n + 1)                      # cycles, energies
+        if dmax and not self.shared_l1:
+            # route columns per CN, then the producer's column per edge
+            per_row += (n + 1) * (n_cores + dmax) * (n_chan + 1)
+            if pieces is not None:
+                per_row += pieces[0].size          # `held`, per reader
+        if self.model_spills:
+            per_row += n_levels * width            # spill share
+        if self.contention == "backlog":
+            per_row += n_levels * width            # core free time
+        if self.segment and not self.strict_layers:
+            per_row += 2 * self.n_layers           # weight cap, held bytes
+        self.core_selects_per_row = per_row
 
         # numpy copies for the float64 lower bound
         self._np_pred_ids = pred_ids
@@ -367,23 +404,24 @@ class BatchedFitness:
         j = self._j
         p = cores_gl.shape[0]
         n_cores = self.n_cores
-        rows = jnp.arange(p)
+        on_core = jnp.arange(n_cores)[:, None]
 
         def step(carry, x):
-            acc_w, seg = carry
+            acc_w, seg = carry                         # (C, P), (P,)
             core, wb = x
-            cap = j["w_cap"][core]
+            cap = core_select(core, j["w_cap"])
             hold = jnp.minimum(wb, cap)
-            held = jnp.take_along_axis(acc_w, core[:, None], axis=1)[:, 0]
+            held = core_select(core, acc_w)
             active = (wb > 0) & (cap > 0)
             cut = active & (held + hold > cap) & (held > 0)
             seg = seg + cut.astype(seg.dtype)
-            acc_w = jnp.where(cut[:, None], 0.0, acc_w)
+            acc_w = jnp.where(cut[None], 0.0, acc_w)
+            # adding 0.0 leaves every other core's footprint as it was
             add = jnp.where(active, hold, 0.0)
-            acc_w = acc_w.at[rows, core].add(add)
+            acc_w = acc_w + jnp.where(core == on_core, add, 0.0)
             return (acc_w, seg), seg
 
-        init = (jnp.zeros((p, n_cores), jnp.float32),
+        init = (jnp.zeros((n_cores, p), jnp.float32),
                 jnp.zeros(p, jnp.int32))
         (_, _), segs = jax.lax.scan(
             step, init, (cores_gl.T, j["layer_wb"]))
@@ -415,13 +453,8 @@ class BatchedFitness:
         # SIMD-friendly minor dimension
         core_ng = genomes.T[j["layer_pad"]]           # (n+1, P)
         seg_ng = seg_gl.T[j["layer_pad"]]
-        ids_pad = jnp.arange(n + 1)[:, None]
-        cyc_ng = j["cyc_nc"][ids_pad, core_ng]        # (n+1, P)
-        ecs_ng = j["ecs_nc"][ids_pad, core_ng]
-
-        if getattr(self, "_debug_stop_after_gather", False):
-            s0 = jnp.sum(cyc_ng) + jnp.sum(ecs_ng) + jnp.sum(seg_ng)
-            return s0, s0
+        cyc_ng = core_select(core_ng, j["cyc_cn"])    # (n+1, P)
+        ecs_ng = core_select(core_ng, j["ecs_cn"])
 
         # fresh-byte dedup, the engine's shipping rule (`repro.core.
         # scheduler`) hoisted out of the time loop (it depends only on the
@@ -429,8 +462,9 @@ class BatchedFitness:
         fresh8_pred = None
         if not self.shared_l1 and self.dmax and "piece_b" in j:
             # footprints: a piece crosses to a core with its first reader
-            # there that sits on another core than the producer (`held`:
-            # which cores have it so far; core n_cores pads the readers)
+            # there that sits on another core than the producer (`held[c]`:
+            # whether core c has it so far; core n_cores pads the readers,
+            # which never cross)
             scr = core_ng[j["succ_ids"]]                  # (n+1, S, P)
             crossing = (j["succ_b"][:, :, None] > 0) & (
                 scr != core_ng[:, None])
@@ -438,17 +472,14 @@ class BatchedFitness:
                 crossing.reshape(-1, p), jnp.zeros((1, p), bool)])
             flat_core = jnp.concatenate([
                 scr.reshape(-1, p), jnp.full((1, p), n_cores, scr.dtype)])
-            held = jnp.zeros((j["piece_b"].shape[0], n_cores + 1, p), bool)
+            held = [jnp.zeros((j["piece_b"].shape[0], p), bool)] * n_cores
             fresh = jnp.zeros(flat_c.shape, jnp.float32)
             for k in range(j["piece_slot"].shape[1]):
                 slot = j["piece_slot"][:, k]              # (Np,), -1 = none
                 c_k = flat_core[slot]                     # (Np, P)
                 x_k = flat_c[slot]
-                on = jnp.take_along_axis(held, c_k[:, None], axis=1)[:, 0]
-                pays = x_k & ~on
-                held = held | (x_k[:, None]
-                               & (c_k[:, None] == jnp.arange(n_cores + 1)
-                                  [None, :, None]))
+                pays = x_k & ~core_select(c_k, held)
+                held = [h | (x_k & (c_k == c)) for c, h in enumerate(held)]
                 fresh = fresh.at[slot].add(
                     jnp.where(pays, j["piece_b"][:, None], 0.0))
             fresh8_pred = 8.0 * fresh[:-1].reshape(
@@ -475,11 +506,6 @@ class BatchedFitness:
             fresh_succ = jnp.stack(fresh_cols, axis=1)    # (n+1, S, P)
             fresh8_pred = 8.0 * fresh_succ[
                 j["pred_ids"], j["edge_slot"]]            # (n+1, D, P)
-
-        if getattr(self, "_debug_stop_after_fresh", False):
-            s0 = jnp.sum(cyc_ng) + (jnp.sum(fresh8_pred)
-                                    if fresh8_pred is not None else 0.0)
-            return s0, s0
 
         # hoist every genome-dependent per-wavefront gather AND every
         # carry-independent per-level reduction out of the scan: the scan
@@ -514,9 +540,19 @@ class BatchedFitness:
             pucn = core_ng[j["pred_ids"]]              # (n+1, D, P)
             crossn = (j["pred_b"][:, :, None] > 0) & (pucn != core_ng[:, None])
             f8n = fresh8_pred * crossn                 # (n+1, D, P)
-            occn = jnp.sum(
-                f8n[..., None] * j["route_inv"][pucn, core_ng[:, None]],
-                axis=1)                                # (n+1, P, n_chan)
+
+            def route(table):
+                """(C, C, ...) core-pair table -> (n+1, D, P, ...) at each
+                edge's (producer, consumer) cores: each producer core's
+                column at the consumer's core once per CN, then the
+                producer's column per edge."""
+                tail = (None,) * (table.ndim - 2)
+                cols = [core_select(core_ng[(...,) + tail], table[u])[:, None]
+                        for u in range(n_cores)]       # (n+1, 1, P, ...)
+                return core_select(pucn[(...,) + tail], cols)
+
+            occn = jnp.sum(f8n[..., None] * route(j["route_inv"]),
+                           axis=1)                     # (n+1, P, n_chan)
             xs["cross"] = crossn[wf]                   # (L, W, D, P)
             xs["occ"] = jnp.moveaxis(occn, 2, 1)[wf].transpose(0, 2, 1, 3)
         if self.model_spills:
@@ -557,12 +593,6 @@ class BatchedFitness:
                     for c in range(n_cores)]
                 fc = fc + jnp.stack(cols, axis=1)      # (L, C, P)
             xs["fc"] = fc
-
-        if getattr(self, "_debug_stop_after_hoist", False):
-            acc0 = jnp.zeros((), jnp.float32)
-            for v in jax.tree_util.tree_leaves(xs):
-                acc0 = acc0 + jnp.sum(v.astype(jnp.float32))
-            return acc0, acc0
 
         def pmax0(a):
             """Inclusive prefix max along axis 0 by shift-doubling."""
@@ -626,12 +656,13 @@ class BatchedFitness:
                 fin_c, core_free = self._serialize_t(core_free, rel_c, dur_c)
                 fin_w = jnp.sum(jnp.where(on_core, fin_c, 0.0), axis=0)
             else:
-                cf_w = jnp.take_along_axis(core_free, x["cw"], axis=0)
+                cf_w = core_select(x["cw"], core_free)
                 fin_w = jnp.where(mem, jnp.maximum(ready, cf_w) + x["cyc"],
                                   0.0)
-                core_free = (core_free + x["sc"]).at[
-                    x["cw"], jnp.arange(p)[None]].max(
-                        jnp.where(mem, fin_w, NEG))
+                done = jnp.where(mem, fin_w, NEG)
+                core_free = jnp.maximum(core_free + x["sc"], jnp.stack([
+                    jnp.max(jnp.where(x["cw"] == c, done, NEG), axis=0)
+                    for c in range(n_cores)]))
 
             # activation-memory occupancy and spills, aggregated per
             # wavefront: overflow beyond a core's activation capacity is
@@ -643,7 +674,7 @@ class BatchedFitness:
                 over = jnp.clip(used + alloc_c - j["act_cap"][:, None],
                                 0.0, alloc_c)
                 frac = over / jnp.maximum(alloc_c, 1.0)
-                frac_w = jnp.take_along_axis(frac, x["mw"], axis=0)
+                frac_w = core_select(x["mw"], frac)
                 spilled = spilled.at[x["wf"]].add(
                     jnp.where(mem, x["aw"] * frac_w, 0.0))
                 dram_x = dram_x + jnp.sum(over, axis=0)
@@ -687,8 +718,8 @@ class BatchedFitness:
         energy = (jnp.sum(ecs_ng[:n], axis=0) + self._dram_e_const
                   + dram_x * self._dram_e_per_byte)
         if comm:
-            energy = energy + jnp.sum(
-                f8n * j["route_e"][pucn, core_ng[:, None]], axis=(0, 1))
+            energy = energy + jnp.sum(f8n * route(j["route_e"]),
+                                      axis=(0, 1))
         return latency, energy
 
     # ---- public API -------------------------------------------------------
@@ -704,7 +735,9 @@ class BatchedFitness:
 
         With a tracer on the engine, the call is a `fitness.scores` span and
         each blocking fetch of a chunk's results a `fitness.wait` span in
-        it, so host preparation and the wait for the device come apart."""
+        it, so host preparation and the wait for the device come apart;
+        the `fitness.core_selects` counter adds the elements each chunk's
+        program looks up by `core_select`."""
         tracer = self.engine.tracer
         if tracer is None:
             return self._scores(genomes, None)
@@ -724,6 +757,9 @@ class BatchedFitness:
                 part = np.concatenate(
                     [part, np.repeat(part[-1:], chunk - m, axis=0)])
             lat, en = self._score_fn(jnp.asarray(part, dtype=jnp.int32))
+            if tracer is not None:
+                tracer.count("fitness.core_selects",
+                             chunk * self.core_selects_per_row)
             with (_NO_SPAN if tracer is None
                   else tracer.span("fitness.wait")):
                 out[o:o + m, 0] = np.asarray(lat, dtype=np.float64)[:m]

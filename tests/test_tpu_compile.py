@@ -8,7 +8,9 @@ topology is described inside a fixture, never at import: only one process
 may load the TPU library, and every test worker imports this file.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +122,28 @@ def test_batched_fitness_score_program_compiles(one_chip, native_pallas,
     genomes = _struct((32, bf.n_layers), jnp.int32, one_chip)
     compiled = bf._score_fn.lower(genomes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_llm_fitness_program_has_no_large_per_element_gather(
+        one_chip, native_pallas, llm_fitness):
+    """The LLM cell's 32-row fitness program looks its core-indexed tables
+    up by selection: no gather of single elements (`offset_dims={}`) as
+    large as one value per CN and row is left. Row gathers, which copy
+    whole 32-row slices, stay; so does the segment barrier's (W, P) one."""
+    bf = llm_fitness
+    rows = 32
+    genomes = _struct((rows, bf.n_layers), jnp.int32, one_chip)
+    hlo = bf._score_fn.lower(genomes).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    large = []
+    for line in hlo.splitlines():
+        if " gather(" not in line or "offset_dims={}" not in line:
+            continue
+        dims = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+        if math.prod(int(d) for d in dims.split(",") if d) >= (
+                bf.n + 1) * rows:
+            large.append(line.split(" gather(")[0].strip())
+    assert large == []
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode_step"])

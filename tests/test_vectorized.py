@@ -17,8 +17,8 @@ from repro.core import CostModel, build_graph
 from repro.core.allocator import feasible_cores_per_layer
 from repro.core.ga import GeneticAllocator
 from repro.core.scheduler import ScheduleEngine
-from repro.core.vectorized import (BatchedFitness, get_batched_fitness,
-                                   rank_correlation)
+from repro.core.vectorized import (BIG, NEG, BatchedFitness, core_select,
+                                   get_batched_fitness, rank_correlation)
 from repro.hw.catalog import (mc_hetero, mc_hom_tpu, mc_hom_tpu_chip2,
                               mc_hom_tpu_chip4)
 
@@ -205,3 +205,123 @@ def test_explore_prefilter_bit_identity():
         assert r0.peak_mem_bytes == r1.peak_mem_bytes
         assert np.array_equal(r0.allocation, r1.allocation)
         assert r1.ga.evaluations <= r0.ga.evaluations
+
+
+# ---- core-axis lookups by selection ------------------------------------------
+
+def _core_table(rng, shape, dtype):
+    """A table whose entries include `BIG` and `NEG`, or a bool table."""
+    if dtype is bool:
+        return rng.random(shape) < 0.5
+    t = rng.uniform(0.0, 1e6, size=shape).astype(np.float32)
+    t.flat[::3] = BIG
+    t.flat[1::5] = NEG
+    return t
+
+
+@pytest.mark.parametrize("dtype", [np.float32, bool])
+@pytest.mark.parametrize("use", ["column", "route", "held"])
+@pytest.mark.parametrize("n_cores", [2, 5, 17])
+def test_core_select_equals_fancy_indexing(n_cores, use, dtype):
+    """`core_select` returns the table's own bits at the index shapes of
+    its uses: a per-CN column picked by the CN's core, a core-pair table
+    picked by each edge's producer and consumer cores, and a per-core
+    (pieces, rows) table picked by each reader's core, the padding reader
+    one past the last core."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(n_cores)
+    n, d, p = 23, 4, 8
+    core = rng.integers(n_cores, size=(n, p))
+    if use == "column":
+        table = _core_table(rng, (n, n_cores), dtype)       # (CN, core)
+        want = np.take_along_axis(table, core, axis=1)
+        got = core_select(jnp.asarray(core), jnp.asarray(table.T[:, :, None]))
+    elif use == "route":
+        table = _core_table(rng, (n_cores, n_cores, 3), dtype)
+        pu = rng.integers(n_cores, size=(n, d, p))          # producers' cores
+        want = table[pu, core[:, None]]                     # (n, d, p, 3)
+        cols = [core_select(jnp.asarray(core)[..., None], jnp.asarray(t))
+                [:, None] for t in table]
+        got = core_select(jnp.asarray(pu)[..., None], cols)
+    else:
+        table = _core_table(rng, (n, n_cores + 1, p), dtype)
+        table[:, n_cores] = table[:, n_cores - 1]           # the pad's entry
+        c_k = rng.integers(n_cores + 1, size=(n, p))
+        want = np.take_along_axis(table, c_k[:, None], axis=1)[:, 0]
+        got = core_select(jnp.asarray(c_k),
+                          [jnp.asarray(table[:, c]) for c in range(n_cores)])
+    got = np.asarray(got)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+# `BatchedFitness.scores` of `_population(w, acc, 8, seed=17)` as float32
+# hex, row-major (latency, energy): the values the gathers gave, which the
+# selects must keep bit for bit
+GOLDEN_SCORES = {
+    ("squeezenet", "backlog"): """
+        0x1.61c9f8p+23 0x1.2609eap+30 0x1.b38a1p+21 0x1.f97a5cp+29
+        0x1.2d7c52p+21 0x1.e07ed4p+29 0x1.c22ee6p+21 0x1.128b44p+30
+        0x1.1799b8p+22 0x1.0c06eep+30 0x1.0a19d4p+22 0x1.0b20dcp+30
+        0x1.1a1634p+22 0x1.2731b2p+30 0x1.dccb36p+21 0x1.1d6e3p+30""",
+    ("squeezenet", "serialize"): """
+        0x1.64a354p+23 0x1.2609eap+30 0x1.b9e93cp+21 0x1.f97a5cp+29
+        0x1.31d8aap+21 0x1.e07ed4p+29 0x1.c86bfep+21 0x1.128b44p+30
+        0x1.198ffp+22 0x1.0c06eep+30 0x1.0c97ep+22 0x1.0b20dcp+30
+        0x1.1b099cp+22 0x1.2731b2p+30 0x1.e1783ep+21 0x1.1d6e3p+30""",
+    ("llm", "backlog"): """
+        0x1.a4118p+20 0x1.d36e5ap+23 0x1.6f8b8p+20 0x1.b317d6p+23
+        0x1.0387a4p+21 0x1.d6e146p+23 0x1.9a1fp+20 0x1.c69ae4p+23
+        0x1.bf4bf8p+20 0x1.f867c8p+23 0x1.8b5afp+20 0x1.b1cb6p+23
+        0x1.91f9p+20 0x1.c8f9c4p+23 0x1.86927p+20 0x1.bf75aap+23""",
+    ("llm", "serialize"): """
+        0x1.ae31a8p+20 0x1.d36e5ap+23 0x1.6ff9p+20 0x1.b317d6p+23
+        0x1.0404b4p+21 0x1.d6e146p+23 0x1.9c5fdp+20 0x1.c69ae4p+23
+        0x1.c5ea78p+20 0x1.f867c8p+23 0x1.8d61cp+20 0x1.b1cb6p+23
+        0x1.9812p+20 0x1.c8f9c4p+23 0x1.8df9f8p+20 0x1.bf75aap+23""",
+}
+
+
+def _golden_engine(graph):
+    acc = mc_hetero()
+    if graph == "squeezenet":
+        return _engine(acc)
+    from test_llm_workload import graph_of, small
+    w = small()
+    return w, ScheduleEngine(graph_of(w, acc), CostModel(w, acc), acc)
+
+
+@pytest.mark.parametrize("graph,contention", sorted(GOLDEN_SCORES))
+def test_scores_bit_identical_to_the_golden(graph, contention):
+    """Squeezenet (bus transfers, fused segments, spills) and the small
+    DeepSeek-V2-Lite graph (footprint pieces) under both contention
+    models."""
+    w, engine = _golden_engine(graph)
+    pop = _population(w, mc_hetero(), 8, seed=17)
+    got = BatchedFitness(engine, contention=contention).scores(pop)
+    want = np.array([float.fromhex(h) for h in
+                     GOLDEN_SCORES[graph, contention].split()]).reshape(8, 2)
+    assert got.tobytes() == want.tobytes()
+
+
+def test_core_selects_counter_per_chunk():
+    """Traced `scores` adds the elements each chunk's program looks up by
+    selection: rows padded to the chunk, times the static count a row."""
+    from repro.obs import Tracer
+    acc = mc_hetero()
+    w, engine = _engine(acc)
+    bf = BatchedFitness(engine, max_batch=8)
+    # cycles and energies, route columns and producer picks (one bus),
+    # spill shares, backlog core times, weight caps and held bytes
+    n1, lw = bf.n + 1, bf.n_wavefronts * bf.width
+    assert bf.n_chan == 1 and bf.core_selects_per_row == (
+        2 * n1 + n1 * (bf.n_cores + bf.dmax) * 2 + 2 * lw
+        + 2 * bf.n_layers)
+    engine.tracer = tr = Tracer()
+    try:
+        bf.scores(_population(w, acc, 5, seed=2))      # one chunk of 8
+        bf.scores(_population(w, acc, 11, seed=3))     # two chunks of 8
+    finally:
+        engine.tracer = None
+    got = tr.snapshot()["counters"]["fitness.core_selects"]
+    assert got == 3 * 8 * bf.core_selects_per_row
