@@ -3,7 +3,7 @@ Pallas kernels go through the interpreter, and where compiled programs are
 cached.
 
 Every device-dependent default in the repo (Pallas `interpret=`, the
-batched-fitness contention model) reads `platform()`, so a TPU run never
+batched fitness's `use_pallas`) reads `platform()`, so a TPU run never
 quietly takes a CPU branch: interpret mode is chosen only when the work
 really lands on the CPU.
 

@@ -11,12 +11,16 @@ evaluates a whole `(P, G)` population at once:
   from predecessor finishes, channel transfers, DRAM weight/input fetches
   and fused-stack barriers, all batched over the population axis;
 * FCFS contention (cores, bus/link channels, the DRAM port) is
-  approximated as per-resource *prefix serialization* within the wavefront:
-  the queue recurrence ``f_k = max(f_{k-1}, r_k) + d_k`` unrolls into
-  cumsum/cummax prefix ops (`repro.kernels.ref.serialize_prefix_ref`), and
-  the `(P x n_cores)` per-wavefront resource update runs as a Pallas kernel
+  approximated as per-resource *prefix serialization* within the wavefront,
+  the one contention model on every platform: the queue recurrence
+  ``f_k = max(f_{k-1}, r_k) + d_k`` unrolls into cumsum/cummax prefix ops
+  (`repro.kernels.ref.serialize_prefix_ref`), and the per-wavefront core
+  and channel updates run as a Pallas kernel
   (`repro.kernels.wavefront.serialize_prefix`) when `use_pallas` is on —
-  interpreted only on the CPU (`repro.backend`).
+  interpreted only on the CPU (`repro.backend`). `use_pallas` picks the
+  backend, never the scores' model;
+* activation-memory overflow is always modelled: spilled bytes are written
+  to and read back from DRAM through the port's busy time and energy.
 
 The result is a *fitness approximation*: global heap order collapses to
 wavefront order, fresh-byte dedup and spill feedback are dropped, weights
@@ -112,14 +116,13 @@ class BatchedFitness:
 
     `use_pallas=None` enables the Pallas serialization kernel unless the
     work runs on the CPU (`repro.backend.platform`); `True` forces it
-    (interpreted on CPU), `False` keeps the pure-jnp reference path.
+    (interpreted on CPU), `False` keeps the pure-jnp reference path. Both
+    run the same contention and spill model.
     """
 
     def __init__(self, engine, priority: str = "latency",
                  segment: bool = True, strict_layers: bool = False,
-                 use_pallas: bool | None = None,
-                 contention: str | None = None, model_spills: bool = True,
-                 max_batch: int = 256):
+                 use_pallas: bool | None = None, max_batch: int = 256):
         if priority not in ("latency", "memory"):
             raise ValueError(f"unknown priority {priority!r}")
         self.engine = engine
@@ -130,26 +133,9 @@ class BatchedFitness:
         import jax
 
         from repro.backend import platform
-        device = platform() != "cpu"
         if use_pallas is None:
-            use_pallas = device
+            use_pallas = platform() != "cpu"
         self.use_pallas = bool(use_pallas)
-        # per-resource queue model: "serialize" is the full intra-wavefront
-        # prefix serialization (the Pallas kernel's job — worth it on device
-        # backends); "backlog" is its saturated-queue specialization
-        # (`f_i = max(r_i, free) + d_i`, `free += sum(d)` — exact whenever
-        # the resource never idles inside a wavefront), the better
-        # throughput/fidelity point on the CPU interpreter path
-        if contention is None:
-            contention = "serialize" if device else "backlog"
-        if contention not in ("serialize", "backlog"):
-            raise ValueError(f"unknown contention model {contention!r}")
-        self.contention = contention
-        self.model_spills = bool(model_spills)
-        # modest scan unroll amortizes XLA's per-step loop dispatch on the
-        # CPU backend; kept at 1 under serialize, whose per-step Pallas
-        # serialization would multiply program size for no dispatch win
-        self._scan_unroll = 1 if contention == "serialize" else 4
         self._build_static()
         self._score_fn = jax.jit(self._score)
 
@@ -356,22 +342,6 @@ class BatchedFitness:
         lvl_oh[np.arange(n), level] = 1.0
         self._j["lvl_oh"] = jnp.asarray(lvl_oh)
 
-        # elements one genome row looks up with `core_select` in one call
-        # of the program: the `fitness.core_selects` counter's unit
-        per_row = 2 * (n + 1)                      # cycles, energies
-        if dmax and not self.shared_l1:
-            # route columns per CN, then the producer's column per edge
-            per_row += (n + 1) * (n_cores + dmax) * (n_chan + 1)
-            if pieces is not None:
-                per_row += pieces[0].size          # `held`, per reader
-        if self.model_spills:
-            per_row += n_levels * width            # spill share
-        if self.contention == "backlog":
-            per_row += n_levels * width            # core free time
-        if self.segment and not self.strict_layers:
-            per_row += 2 * self.n_layers           # weight cap, held bytes
-        self.core_selects_per_row = per_row
-
         # numpy copies for the float64 lower bound
         self._np_pred_ids = pred_ids
         self._np_cyc64 = np.where(feas, tab.cycles, BIG)[sig]  # (n, C)
@@ -519,17 +489,9 @@ class BatchedFitness:
         xs = {"wf": wf, "member": member, "cyc": cyc_x, "seg": seg_x,
               "cw": cw_x, "dram": j["dram_off"], "tot": j["dram_tot"]}
         comm = self.dmax and not self.shared_l1
-        serialize = self.contention == "serialize"
         on = ((cw_x[:, None] == jnp.arange(n_cores)[None, :, None, None])
               & member[:, None, :, None])              # (L, C, W, P)
-        if serialize:
-            xs["on"] = on
-        else:
-            # backlog mode reduces `on` away up front (per-core added queue
-            # occupancy of the whole wavefront) and scatter-maxes the
-            # per-core frontier in-step, so the big mask never enters xs
-            xs["sc"] = jnp.sum(jnp.where(on, cyc_x[:, None], 0.0),
-                               axis=2)                 # (L, C, P)
+        xs["on"] = on
         if self.dmax:
             xs["pu"] = j["wf_pred"]                    # (L, W, D)
         if comm:
@@ -555,44 +517,43 @@ class BatchedFitness:
                            axis=1)                     # (n+1, P, n_chan)
             xs["cross"] = crossn[wf]                   # (L, W, D, P)
             xs["occ"] = jnp.moveaxis(occn, 2, 1)[wf].transpose(0, 2, 1, 3)
-        if self.model_spills:
-            # bytes allocated per CN on its memory-pool core (own outputs,
-            # external inputs, and incoming fresh activations) and bytes
-            # freed when the wavefront retires (fully-consumed inputs plus
-            # the incoming copies themselves) — reduced to per-core (L, C,
-            # P) sums here so the scan only tracks occupancy vs capacity
-            aw = jnp.broadcast_to(j["alloc_b"][:, :, None], cyc_x.shape)
-            fw = jnp.broadcast_to(j["disc_b"][:, :, None], cyc_x.shape)
-            if comm:
-                # incoming fresh copies land on the consumer's memory core
-                fbn = jnp.sum(f8n, axis=1) / 8.0       # (n+1, P)
-                aw = aw + fbn[wf]
-            aw = jnp.where(member[:, :, None], aw, 0.0)    # (L, W, P)
-            if self.shared_l1:
-                # activations pool on core 0 under shared L1
-                onm = (member[:, None, :, None] &
-                       (jnp.arange(n_cores)[None, :, None, None] == 0))
-                xs["mw"] = jnp.zeros_like(cw_x)
-            else:
-                onm = on
-                xs["mw"] = cw_x
-            xs["aw"] = aw
-            xs["ac"] = jnp.sum(jnp.where(onm, aw[:, None], 0.0), axis=2)
-            fc = jnp.sum(jnp.where(onm, fw[:, None], 0.0), axis=2)
-            if comm:
-                # ...and are freed from the *producer's* core when the
-                # consumer finishes: per-core mask-sums over the pred view
-                # plus one static matmul onto the consumer's level (at
-                # full f32 precision: the TPU's default rounds operands to
-                # bf16, which would blur the byte counts)
-                fbe = f8n / 8.0                        # (n+1, D, P)
-                lvl_t = j["lvl_oh"].T                  # (L, n+1)
-                cols = [jnp.matmul(
-                    lvl_t, jnp.sum(jnp.where(pucn == c, fbe, 0.0), axis=1),
-                    precision=jax.lax.Precision.HIGHEST)
-                    for c in range(n_cores)]
-                fc = fc + jnp.stack(cols, axis=1)      # (L, C, P)
-            xs["fc"] = fc
+        # bytes allocated per CN on its memory-pool core (own outputs,
+        # external inputs, and incoming fresh activations) and bytes
+        # freed when the wavefront retires (fully-consumed inputs plus
+        # the incoming copies themselves) — reduced to per-core (L, C,
+        # P) sums here so the scan only tracks occupancy vs capacity
+        aw = jnp.broadcast_to(j["alloc_b"][:, :, None], cyc_x.shape)
+        fw = jnp.broadcast_to(j["disc_b"][:, :, None], cyc_x.shape)
+        if comm:
+            # incoming fresh copies land on the consumer's memory core
+            fbn = jnp.sum(f8n, axis=1) / 8.0           # (n+1, P)
+            aw = aw + fbn[wf]
+        aw = jnp.where(member[:, :, None], aw, 0.0)    # (L, W, P)
+        if self.shared_l1:
+            # activations pool on core 0 under shared L1
+            onm = (member[:, None, :, None] &
+                   (jnp.arange(n_cores)[None, :, None, None] == 0))
+            xs["mw"] = jnp.zeros_like(cw_x)
+        else:
+            onm = on
+            xs["mw"] = cw_x
+        xs["aw"] = aw
+        xs["ac"] = jnp.sum(jnp.where(onm, aw[:, None], 0.0), axis=2)
+        fc = jnp.sum(jnp.where(onm, fw[:, None], 0.0), axis=2)
+        if comm:
+            # ...and are freed from the *producer's* core when the
+            # consumer finishes: per-core mask-sums over the pred view
+            # plus one static matmul onto the consumer's level (at
+            # full f32 precision: the TPU's default rounds operands to
+            # bf16, which would blur the byte counts)
+            fbe = f8n / 8.0                            # (n+1, D, P)
+            lvl_t = j["lvl_oh"].T                      # (L, n+1)
+            cols = [jnp.matmul(
+                lvl_t, jnp.sum(jnp.where(pucn == c, fbe, 0.0), axis=1),
+                precision=jax.lax.Precision.HIGHEST)
+                for c in range(n_cores)]
+            fc = fc + jnp.stack(cols, axis=1)          # (L, C, P)
+        xs["fc"] = fc
 
         def pmax0(a):
             """Inclusive prefix max along axis 0 by shift-doubling."""
@@ -615,16 +576,8 @@ class BatchedFitness:
                                     initial=NEG)       # (W, P) bundle release
                     occ_t = x["occ"]                   # (n_chan, W, P)
                     rel_t = jnp.where(occ_t > 0, rel_b[None], NEG)
-                    if serialize:
-                        fin_ch, chan_free = self._serialize_t(
-                            chan_free, rel_t, occ_t)
-                    else:
-                        fin_ch = jnp.maximum(rel_t,
-                                             chan_free[:, None]) + occ_t
-                        chan_free = jnp.maximum(
-                            chan_free + jnp.sum(occ_t, axis=1),
-                            jnp.max(jnp.where(occ_t > 0, fin_ch, NEG),
-                                    axis=1))
+                    fin_ch, chan_free = self._serialize_t(
+                        chan_free, rel_t, occ_t)
                     arr = jnp.max(jnp.where(occ_t > 0, fin_ch, NEG), axis=0)
                     data_ready = jnp.maximum(base, arr)
                 else:
@@ -649,38 +602,28 @@ class BatchedFitness:
 
             # per-core FCFS queue update — the (n_cores x P) step
             mem = x["member"][:, None]
-            if serialize:
-                on_core = x["on"]                      # (C, W, P)
-                rel_c = jnp.where(on_core, ready[None], NEG)
-                dur_c = jnp.where(on_core, x["cyc"][None], 0.0)
-                fin_c, core_free = self._serialize_t(core_free, rel_c, dur_c)
-                fin_w = jnp.sum(jnp.where(on_core, fin_c, 0.0), axis=0)
-            else:
-                cf_w = core_select(x["cw"], core_free)
-                fin_w = jnp.where(mem, jnp.maximum(ready, cf_w) + x["cyc"],
-                                  0.0)
-                done = jnp.where(mem, fin_w, NEG)
-                core_free = jnp.maximum(core_free + x["sc"], jnp.stack([
-                    jnp.max(jnp.where(x["cw"] == c, done, NEG), axis=0)
-                    for c in range(n_cores)]))
+            on_core = x["on"]                          # (C, W, P)
+            rel_c = jnp.where(on_core, ready[None], NEG)
+            dur_c = jnp.where(on_core, x["cyc"][None], 0.0)
+            fin_c, core_free = self._serialize_t(core_free, rel_c, dur_c)
+            fin_w = jnp.sum(jnp.where(on_core, fin_c, 0.0), axis=0)
 
             # activation-memory occupancy and spills, aggregated per
             # wavefront: overflow beyond a core's activation capacity is
             # written out (`spill_w`) and every consumer edge of a spilled
             # producer reads its share back (`spill_r`), both through the
             # DRAM port — the term that dominates exact-energy variance
-            if self.model_spills:
-                alloc_c = x["ac"]                      # (C, P)
-                over = jnp.clip(used + alloc_c - j["act_cap"][:, None],
-                                0.0, alloc_c)
-                frac = over / jnp.maximum(alloc_c, 1.0)
-                frac_w = core_select(x["mw"], frac)
-                spilled = spilled.at[x["wf"]].add(
-                    jnp.where(mem, x["aw"] * frac_w, 0.0))
-                dram_x = dram_x + jnp.sum(over, axis=0)
-                used = jnp.maximum(
-                    jnp.minimum(used + alloc_c - over, j["act_cap"][:, None])
-                    - x["fc"], 0.0)
+            alloc_c = x["ac"]                          # (C, P)
+            over = jnp.clip(used + alloc_c - j["act_cap"][:, None],
+                            0.0, alloc_c)
+            frac = over / jnp.maximum(alloc_c, 1.0)
+            frac_w = core_select(x["mw"], frac)
+            spilled = spilled.at[x["wf"]].add(
+                jnp.where(mem, x["aw"] * frac_w, 0.0))
+            dram_x = dram_x + jnp.sum(over, axis=0)
+            used = jnp.maximum(
+                jnp.minimum(used + alloc_c - over, j["act_cap"][:, None])
+                - x["fc"], 0.0)
 
             finish = finish.at[x["wf"]].set(fin_w)
             seg_front = seg_front.at[x["seg"], jnp.arange(p)[None]].max(
@@ -697,9 +640,9 @@ class BatchedFitness:
                  jnp.zeros((n + 1, p), jnp.float32),
                  jnp.zeros(p, jnp.float32))
         (finish, core_free, chan_free, dram_free, _, _, spilled, dram_x), _ \
-            = jax.lax.scan(step, state, xs, unroll=self._scan_unroll)
+            = jax.lax.scan(step, state, xs)
 
-        if self.model_spills and self.dmax:
+        if self.dmax:
             # spill readback resolves post-scan: a CN spills exactly once,
             # at its own level, and every consumer sits at a strictly later
             # level — so the per-edge min(spilled[producer], edge_bytes)
@@ -735,9 +678,7 @@ class BatchedFitness:
 
         With a tracer on the engine, the call is a `fitness.scores` span and
         each blocking fetch of a chunk's results a `fitness.wait` span in
-        it, so host preparation and the wait for the device come apart;
-        the `fitness.core_selects` counter adds the elements each chunk's
-        program looks up by `core_select`."""
+        it, so host preparation and the wait for the device come apart."""
         tracer = self.engine.tracer
         if tracer is None:
             return self._scores(genomes, None)
@@ -757,25 +698,11 @@ class BatchedFitness:
                 part = np.concatenate(
                     [part, np.repeat(part[-1:], chunk - m, axis=0)])
             lat, en = self._score_fn(jnp.asarray(part, dtype=jnp.int32))
-            if tracer is not None:
-                tracer.count("fitness.core_selects",
-                             chunk * self.core_selects_per_row)
             with (_NO_SPAN if tracer is None
                   else tracer.span("fitness.wait")):
                 out[o:o + m, 0] = np.asarray(lat, dtype=np.float64)[:m]
                 out[o:o + m, 1] = np.asarray(en, dtype=np.float64)[:m]
         return out
-
-    def scalar_scores(self, genomes, objective: str = "edp") -> np.ndarray:
-        """Scalarized approximate scores (lower is better)."""
-        if objective not in _OBJECTIVES:
-            raise ValueError(f"unknown objective {objective!r}")
-        s = self.scores(genomes)
-        if objective == "latency":
-            return s[:, 0]
-        if objective == "energy":
-            return s[:, 1]
-        return s[:, 0] * s[:, 1]
 
     def rescore(self, genomes) -> np.ndarray:
         """Exact `(K, 2)` metrics through the Python engine — the oracle the
@@ -830,17 +757,21 @@ class BatchedFitness:
 def get_batched_fitness(engine, priority: str = "latency",
                         segment: bool = True, strict_layers: bool = False,
                         use_pallas: bool | None = None,
-                        contention: str | None = None) -> BatchedFitness:
+                        contention: str = "serialize") -> BatchedFitness:
     """`BatchedFitness` for `engine`, cached on the engine instance so one
     GA run (and every explore() hitting the session's engine cache) pays
     the wavefront precompute and jit trace once per configuration."""
+    # `contention` is kept for tests/bench/benchtools.py (serialize_on_host)
+    # and tests/bench/test_bench_correct_explore_llm.py; it goes with them
+    if contention != "serialize":
+        raise ValueError(f"unknown contention model {contention!r}")
     cache = getattr(engine, "_batched_fitness", None)
     if cache is None:
         cache = engine._batched_fitness = {}
-    key = (priority, segment, strict_layers, use_pallas, contention)
+    key = (priority, segment, strict_layers, use_pallas)
     bf = cache.get(key)
     if bf is None:
         bf = cache[key] = BatchedFitness(
             engine, priority, segment=segment, strict_layers=strict_layers,
-            use_pallas=use_pallas, contention=contention)
+            use_pallas=use_pallas)
     return bf

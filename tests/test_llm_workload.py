@@ -265,7 +265,7 @@ def test_batched_fitness_within_float32_of_the_plain_fitness():
     w, acc = small(), mc_hetero()
     graph = graph_of(w, acc)
     engine = ScheduleEngine(graph, CostModel(w, acc), acc)
-    bf = BatchedFitness(engine, contention="serialize", use_pallas=False)
+    bf = BatchedFitness(engine, use_pallas=False)
     ref = plain.FitnessReference(*plain.problem(w, acc, GRAN), acc)
     feas = feasible_cores_per_layer(w, acc)
     rng = np.random.default_rng(5)

@@ -58,14 +58,14 @@ def native_pallas(monkeypatch):
 @pytest.fixture(scope="module")
 def explorer_fitness():
     """The explorer's device path on resnet18 ("tile", 32, 1) / mc_hetero:
-    the Pallas kernel under the full serialize contention model."""
+    the score program with its Pallas kernel."""
     from repro.api.session import ExplorationSession
     from repro.configs.paper_workloads import resnet18
     from repro.core.vectorized import BatchedFitness
     from repro.hw.catalog import mc_hetero
     engine = ExplorationSession().engine(resnet18(), mc_hetero(),
                                          ("tile", 32, 1))
-    return BatchedFitness(engine, contention="serialize", use_pallas=True)
+    return BatchedFitness(engine, use_pallas=True)
 
 
 def _struct(shape, dtype, sharding):
@@ -98,7 +98,7 @@ def llm_fitness():
     from repro.hw.catalog import mc_hetero
     engine = ExplorationSession().engine(deepseek_v2_lite_prefill(),
                                          mc_hetero(), ("tile", 8, 1))
-    return BatchedFitness(engine, contention="serialize", use_pallas=True)
+    return BatchedFitness(engine, use_pallas=True)
 
 
 def test_serialize_prefix_compiles_at_llm_width(one_chip, native_pallas,

@@ -19,6 +19,7 @@ from repro.core.ga import GeneticAllocator
 from repro.core.scheduler import ScheduleEngine
 from repro.core.vectorized import (BIG, NEG, BatchedFitness, core_select,
                                    get_batched_fitness, rank_correlation)
+from repro.hw import catalog
 from repro.hw.catalog import (mc_hetero, mc_hom_tpu, mc_hom_tpu_chip2,
                               mc_hom_tpu_chip4)
 
@@ -139,21 +140,30 @@ def test_use_pallas_consistency():
     acc = mc_hetero()
     w, engine = _engine(acc)
     pop = _population(w, acc, 8, seed=7)
-    on = BatchedFitness(engine, contention="serialize", use_pallas=True)
-    off = BatchedFitness(engine, contention="serialize", use_pallas=False)
+    on = BatchedFitness(engine, use_pallas=True)
+    off = BatchedFitness(engine, use_pallas=False)
     np.testing.assert_allclose(on.scores(pop), off.scores(pop), rtol=1e-9)
 
 
 def test_contention_models_both_rank(arch_setup):
-    """The backlog specialization (CPU default) and the full serialize
-    model both produce finite, positively-ranking scores."""
+    """The contention model (per-resource prefix serialization, on the CPU
+    through its jnp twin) produces finite, positively-ranking scores."""
     w, acc, engine = arch_setup
     pop = _population(w, acc, 24, seed=9, spread=True)
     exact = engine.evaluate_population(pop, "latency")
-    for contention in ("backlog", "serialize"):
-        s = get_batched_fitness(engine, contention=contention).scores(pop)
-        assert np.all(np.isfinite(s)) and np.all(s > 0)
-        assert rank_correlation(s[:, 0], exact[:, 0]) > 0.25
+    s = get_batched_fitness(engine).scores(pop)
+    assert np.all(np.isfinite(s)) and np.all(s > 0)
+    assert rank_correlation(s[:, 0], exact[:, 0]) > 0.25
+
+
+def test_get_batched_fitness_takes_only_the_serialize_model():
+    """The `contention` keyword names the one model; any other raises."""
+    acc = mc_hetero()
+    _, engine = _engine(acc)
+    bf = get_batched_fitness(engine)
+    assert get_batched_fitness(engine, contention="serialize") is bf
+    with pytest.raises(ValueError, match="contention"):
+        get_batched_fitness(engine, contention="backlog")
 
 
 def test_prefilter_keep_one_is_noop():
@@ -256,34 +266,38 @@ def test_core_select_equals_fancy_indexing(n_cores, use, dtype):
 
 
 # `BatchedFitness.scores` of `_population(w, acc, 8, seed=17)` as float32
-# hex, row-major (latency, energy): the values the gathers gave, which the
-# selects must keep bit for bit
+# hex, row-major (latency, energy), keyed by (graph, architecture)
 GOLDEN_SCORES = {
-    ("squeezenet", "backlog"): """
-        0x1.61c9f8p+23 0x1.2609eap+30 0x1.b38a1p+21 0x1.f97a5cp+29
-        0x1.2d7c52p+21 0x1.e07ed4p+29 0x1.c22ee6p+21 0x1.128b44p+30
-        0x1.1799b8p+22 0x1.0c06eep+30 0x1.0a19d4p+22 0x1.0b20dcp+30
-        0x1.1a1634p+22 0x1.2731b2p+30 0x1.dccb36p+21 0x1.1d6e3p+30""",
-    ("squeezenet", "serialize"): """
-        0x1.64a354p+23 0x1.2609eap+30 0x1.b9e93cp+21 0x1.f97a5cp+29
-        0x1.31d8aap+21 0x1.e07ed4p+29 0x1.c86bfep+21 0x1.128b44p+30
-        0x1.198ffp+22 0x1.0c06eep+30 0x1.0c97ep+22 0x1.0b20dcp+30
-        0x1.1b099cp+22 0x1.2731b2p+30 0x1.e1783ep+21 0x1.1d6e3p+30""",
-    ("llm", "backlog"): """
-        0x1.a4118p+20 0x1.d36e5ap+23 0x1.6f8b8p+20 0x1.b317d6p+23
-        0x1.0387a4p+21 0x1.d6e146p+23 0x1.9a1fp+20 0x1.c69ae4p+23
-        0x1.bf4bf8p+20 0x1.f867c8p+23 0x1.8b5afp+20 0x1.b1cb6p+23
-        0x1.91f9p+20 0x1.c8f9c4p+23 0x1.86927p+20 0x1.bf75aap+23""",
-    ("llm", "serialize"): """
+    ("llm", "mc_hetero"): """
         0x1.ae31a8p+20 0x1.d36e5ap+23 0x1.6ff9p+20 0x1.b317d6p+23
         0x1.0404b4p+21 0x1.d6e146p+23 0x1.9c5fdp+20 0x1.c69ae4p+23
         0x1.c5ea78p+20 0x1.f867c8p+23 0x1.8d61cp+20 0x1.b1cb6p+23
         0x1.9812p+20 0x1.c8f9c4p+23 0x1.8df9f8p+20 0x1.bf75aap+23""",
+    ("llm", "mc_hetero_chip2"): """
+        0x1.ae458p+20 0x1.dd0a5ap+23 0x1.702a8p+20 0x1.bf111ep+23
+        0x1.03f148p+21 0x1.e1e91ep+23 0x1.9c9a2p+20 0x1.d34abcp+23
+        0x1.c5ae08p+20 0x1.fef5dcp+23 0x1.8dd1p+20 0x1.beb0d2p+23
+        0x1.97b4p+20 0x1.d42e3ep+23 0x1.8e60f8p+20 0x1.cae182p+23""",
+    ("squeezenet", "aimc_4x4"): """
+        0x1.bcb19cp+20 0x1.9d1dc8p+26 0x1.025ef6p+21 0x1.9d1dc8p+26
+        0x1.f9256cp+20 0x1.9d1dc8p+26 0x1.02107ep+21 0x1.9d1dc8p+26
+        0x1.ecb0ecp+20 0x1.9d1dc8p+26 0x1.102e46p+21 0x1.9d1dc8p+26
+        0x1.c14b0cp+20 0x1.9d1dc8p+26 0x1.0a36e6p+21 0x1.9d1dc8p+26""",
+    ("squeezenet", "mc_hetero"): """
+        0x1.64a354p+23 0x1.2609eap+30 0x1.b9e93cp+21 0x1.f97a5cp+29
+        0x1.31d8aap+21 0x1.e07ed4p+29 0x1.c86bfep+21 0x1.128b44p+30
+        0x1.198ffp+22 0x1.0c06eep+30 0x1.0c97ep+22 0x1.0b20dcp+30
+        0x1.1b099cp+22 0x1.2731b2p+30 0x1.e1783ep+21 0x1.1d6e3p+30""",
+    ("squeezenet", "mc_hetero_chip2"): """
+        0x1.645d5cp+23 0x1.272f64p+30 0x1.bc71a8p+21 0x1.fd9996p+29
+        0x1.32e696p+21 0x1.e3cbcp+29 0x1.c81afep+21 0x1.1475e2p+30
+        0x1.19f498p+22 0x1.0dc66p+30 0x1.0cd04cp+22 0x1.0d0f36p+30
+        0x1.1b5a7cp+22 0x1.28711cp+30 0x1.e1c5b6p+21 0x1.1f3244p+30""",
 }
 
 
-def _golden_engine(graph):
-    acc = mc_hetero()
+def _golden_engine(graph, arch):
+    acc = getattr(catalog, arch)()
     if graph == "squeezenet":
         return _engine(acc)
     from test_llm_workload import graph_of, small
@@ -291,37 +305,15 @@ def _golden_engine(graph):
     return w, ScheduleEngine(graph_of(w, acc), CostModel(w, acc), acc)
 
 
-@pytest.mark.parametrize("graph,contention", sorted(GOLDEN_SCORES))
-def test_scores_bit_identical_to_the_golden(graph, contention):
+@pytest.mark.parametrize("graph,arch", sorted(GOLDEN_SCORES))
+def test_scores_bit_identical_to_the_golden(graph, arch):
     """Squeezenet (bus transfers, fused segments, spills) and the small
-    DeepSeek-V2-Lite graph (footprint pieces) under both contention
-    models."""
-    w, engine = _golden_engine(graph)
-    pop = _population(w, mc_hetero(), 8, seed=17)
-    got = BatchedFitness(engine, contention=contention).scores(pop)
+    DeepSeek-V2-Lite graph (footprint pieces) on MC:Hetero and on its
+    2-chiplet partition (3 channels, multi-hop routes), and squeezenet on
+    the 17-core shared-L1 AiMC 4x4 (the longest select chains)."""
+    w, engine = _golden_engine(graph, arch)
+    pop = _population(w, engine.accelerator, 8, seed=17)
+    got = BatchedFitness(engine).scores(pop)
     want = np.array([float.fromhex(h) for h in
-                     GOLDEN_SCORES[graph, contention].split()]).reshape(8, 2)
+                     GOLDEN_SCORES[graph, arch].split()]).reshape(8, 2)
     assert got.tobytes() == want.tobytes()
-
-
-def test_core_selects_counter_per_chunk():
-    """Traced `scores` adds the elements each chunk's program looks up by
-    selection: rows padded to the chunk, times the static count a row."""
-    from repro.obs import Tracer
-    acc = mc_hetero()
-    w, engine = _engine(acc)
-    bf = BatchedFitness(engine, max_batch=8)
-    # cycles and energies, route columns and producer picks (one bus),
-    # spill shares, backlog core times, weight caps and held bytes
-    n1, lw = bf.n + 1, bf.n_wavefronts * bf.width
-    assert bf.n_chan == 1 and bf.core_selects_per_row == (
-        2 * n1 + n1 * (bf.n_cores + bf.dmax) * 2 + 2 * lw
-        + 2 * bf.n_layers)
-    engine.tracer = tr = Tracer()
-    try:
-        bf.scores(_population(w, acc, 5, seed=2))      # one chunk of 8
-        bf.scores(_population(w, acc, 11, seed=3))     # two chunks of 8
-    finally:
-        engine.tracer = None
-    got = tr.snapshot()["counters"]["fitness.core_selects"]
-    assert got == 3 * 8 * bf.core_selects_per_row
