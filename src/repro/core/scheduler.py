@@ -62,6 +62,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core import schedpool
 from repro.core.costmodel import CostModel
 from repro.core.depgraph import CNGraph
 from repro.core.workload import ACT_OPERAND_OPS
@@ -305,12 +306,21 @@ class ScheduleEngine:
         self.ckpt_stats = dict.fromkeys(self.CKPT_COUNTERS, 0)
         self._ckpt_store: OrderedDict[tuple, tuple] = OrderedDict()
         self._seg_cache: dict[bytes, tuple[list[int], list[int]]] = {}
+        # replica engines in worker processes for `evaluate_population`
+        # (`repro.core.schedpool`), started on the first batch that walks
+        # at least `_pool_min_walks` CNs; tests lower the two limits
+        self._pool: schedpool.SchedulePool | None = None
+        self._pool_min_walks = schedpool.POOL_MIN_WALKS
+        self._pool_cap = schedpool.POOL_MAX_WORKERS
 
     def reset_checkpoints(self) -> None:
-        """Drop stored snapshots and zero the hit/skip counters."""
+        """Drop stored snapshots and zero the hit/skip counters; the pool's
+        replicas, and their snapshots, are closed with it."""
         self._ckpt_store.clear()
         for k in self.ckpt_stats:
             self.ckpt_stats[k] = 0
+        if self._pool is not None:
+            self._pool.close()
 
     @property
     def checkpoint_hit_rate(self) -> float:
@@ -354,10 +364,24 @@ class ScheduleEngine:
         The population-batched entry point of the GA hot path: one row per
         genome, scheduled against the shared checkpoint store so genomes
         sharing allocation prefixes (parents and their offspring) replay
-        only their differing suffixes."""
+        only their differing suffixes. A batch walking at least
+        `schedpool.POOL_MIN_WALKS` CNs is scored by replica engines in
+        worker processes (`repro.core.schedpool`), bit-identically; with a
+        tracer it is one `engine.pool` span, and its rows are counted in
+        `engine.pooled_rows` and in the counters `schedule` keeps."""
         genomes = np.asarray(genomes, dtype=np.int64)
         if genomes.ndim == 1:
             genomes = genomes[None, :]
+        pool = self._pool_for(genomes.shape[0])
+        if pool is not None:
+            try:
+                return self._evaluate_pooled(pool, genomes, dict(
+                    priority=priority, segment=segment,
+                    strict_layers=strict_layers,
+                    checkpoint=(self.checkpointing if checkpoint is None
+                                else checkpoint)))
+            except schedpool.WorkerLost:
+                pass    # scored here; the next batch starts a new pool
         out = np.empty((genomes.shape[0], 2), dtype=np.float64)
         for r in range(genomes.shape[0]):
             res = self.schedule(genomes[r], priority, segment=segment,
@@ -366,6 +390,46 @@ class ScheduleEngine:
             out[r, 0] = res.latency_cc
             out[r, 1] = res.energy_pj
         return out
+
+    def _pool_for(self, rows: int) -> "schedpool.SchedulePool | None":
+        """The pool for a batch of `rows` genomes, started if need be, or
+        None where the batch stays serial: below `_pool_min_walks` CN
+        walks, or where `schedpool.pool_workers` allows fewer than two."""
+        if rows * self.n < self._pool_min_walks:
+            return None
+        if self._pool is None or self._pool.closed:
+            workers = schedpool.pool_workers(self._pool_cap)
+            if workers < 2:
+                return None
+            self._pool = schedpool.SchedulePool(self, workers)
+        return self._pool
+
+    def _evaluate_pooled(self, pool, genomes: np.ndarray,
+                         kw: dict) -> np.ndarray:
+        tracer = self.tracer
+        if tracer is None:
+            out, resumed, ckpt = pool.evaluate(genomes, kw)
+        else:
+            with tracer.span("engine.pool"):
+                out, resumed, ckpt = pool.evaluate(genomes, kw)
+            tracer.count("engine.pooled_rows", len(resumed))
+            self._count_schedules(tracer, resumed)
+        for k, v in ckpt.items():
+            self.ckpt_stats[k] += v
+        return out
+
+    def _count_schedules(self, tracer, resumed) -> None:
+        """Count schedules that resumed `resumed[i]` CNs each: CN work is
+        counted, not timed (one span per schedule, none per CN), with the
+        edges from per-CN prefix sums."""
+        n, edges, operand = self.n, self._edges_cum, self._operand_cum
+        tracer.count("engine.schedules", len(resumed))
+        tracer.count("engine.cns_walked", sum(n - k for k in resumed))
+        tracer.count("engine.cns_resumed", sum(resumed))
+        tracer.count("engine.edges_walked",
+                     sum(edges[n] - edges[k] for k in resumed))
+        tracer.count("engine.operand_edges",
+                     sum(operand[n] - operand[k] for k in resumed))
 
     def schedule(self, allocation: Sequence[int], priority: str = "latency",
                  segment: bool = True, strict_layers: bool = False,
@@ -888,15 +952,8 @@ class ScheduleEngine:
         )
         tracer = self.tracer
         if tracer is not None:
-            # counted for every schedule, checkpointed or not: CN work is
-            # counted, not timed (one span per schedule, none per CN)
-            tracer.count("engine.schedules")
-            tracer.count("engine.cns_walked", n - n_resumed)
-            tracer.count("engine.cns_resumed", n_resumed)
-            tracer.count("engine.edges_walked",
-                         self._edges_cum[n] - self._edges_cum[n_resumed])
-            tracer.count("engine.operand_edges",
-                         self._operand_cum[n] - self._operand_cum[n_resumed])
+            # counted for every schedule, checkpointed or not
+            self._count_schedules(tracer, (n_resumed,))
         if validate:
             if not record:
                 raise ValueError("validate=True needs record=True "
